@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.kernels import ops
 from repro.kernels.window_score import BW, LANE
 
@@ -38,12 +37,8 @@ def _time(fn, *a, n=3, **kw):
 
 
 def _bench_tiers(op: str) -> list[str]:
-    """Every runnable tier, plus explicit interpret where Pallas exists."""
-    tiers = list(ops.available_tiers(op))
-    if compat.has_pallas(op in ("segment_sum", "flash_attention")):
-        if op != "segment_sum" or compat.HAS_PREFETCH_GRID:
-            tiers.append(ops.INTERPRET_TIER)
-    return tiers
+    """Every runnable tier, plus the explicit interpret debug tier."""
+    return [*ops.available_tiers(op), ops.INTERPRET_TIER]
 
 
 def _row(op: str, shape: str, fn, args, vmem_kb: float) -> dict:

@@ -1,23 +1,21 @@
 """Multi-device scaling harness: batched spotlight partitioning wall and
 engine supersteps/s vs device count.
 
-The container has one physical CPU, so device scaling is measured against
-XLA's fake host devices: for each N the harness spawns a subprocess under
-``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (the flag is read at
-process startup, so it cannot be flipped in-process) and measures, inside it:
+Measured in this process on the real device set: for each N the spotlight
+runs z = N instances (one per device) as ONE program, shard_mapped over
+``jax.devices()[:N]``, next to the sequential ``backend="loop"`` path over
+the same instances, and PageRank runs on an engine mesh over the same N
+devices (the `parts` axis is padded inside `make_superstep`, so every N is
+valid for every k). Device counts above ``jax.device_count()`` are skipped.
 
-  * batched spotlight partitioning (z instances as ONE vmapped/shard_mapped
-    program — instances land on separate devices when N > 1),
-  * the sequential ``backend="loop"`` path on the same host (the z× cost the
-    batched scan removes),
-  * engine supersteps/s for PageRank on the partitioned graph (the `parts`
-    mesh axis is padded inside `make_superstep`, so every N is valid for
-    every k).
+``--rehearse`` is the CPU rehearsal: each N runs in a child process under
+``JAX_PLATFORMS=cpu`` with N virtual host devices (the flag is read at
+process start-up). Its numbers are CPU numbers; never use it where a TPU is
+present, since a parent holding the chip shares nothing with its children.
 
-    PYTHONPATH=src python -m benchmarks.bench_scaling                 # N = 1,2,4,8
-    PYTHONPATH=src python -m benchmarks.bench_scaling --smoke         # CI-size
-    XLA_FLAGS=--xla_force_host_platform_device_count=2 \\
-        PYTHONPATH=src python -m benchmarks.bench_scaling --in-process
+    PYTHONPATH=src python -m benchmarks.bench_scaling                  # N = 1,2,4,8
+    PYTHONPATH=src python -m benchmarks.bench_scaling --smoke          # CI-size
+    PYTHONPATH=src python -m benchmarks.bench_scaling --smoke --rehearse
 """
 from __future__ import annotations
 
@@ -31,17 +29,17 @@ import time
 _JSON_MARK = "BENCH_SCALING_ROW:"
 
 
-def _measure(args) -> dict:
-    """Measure on THIS process's devices (call under the right XLA_FLAGS)."""
+def _measure(args, n_devices: int) -> dict:
+    """Measure on the first ``n_devices`` devices of this process."""
     import jax
     import numpy as np
 
     from repro.core import AdwiseConfig, spotlight_partition
-    from repro.engine import build_partitioned_graph, pagerank
+    from repro.engine import build_partitioned_graph, engine_mesh, pagerank
     from repro.graph import make_graph
 
     edges, n = make_graph(args.graph, seed=0, scale=args.scale)
-    k, z = args.k, args.z
+    k, z = args.k, n_devices
     spread = args.spread if args.spread else max(k // z, 1)
     cfg = AdwiseConfig(k=k, window_max=args.window,
                        window_init=max(1, args.window // 4))
@@ -61,14 +59,16 @@ def _measure(args) -> dict:
 
     g = build_partitioned_graph(edges, res_b.assign, n, k)
     iters = args.iters
-    pagerank(g, iters=2)  # compile
+    mesh = engine_mesh(n_devices=n_devices, k=k)
+    pagerank(g, iters=2, mesh=mesh)  # compile
     t0 = time.perf_counter()
-    pr, _ = pagerank(g, iters=iters)
+    pr, _ = pagerank(g, iters=iters, mesh=mesh)
     t_engine = time.perf_counter() - t0
     assert np.isfinite(pr).all()
 
     return dict(
-        devices=jax.device_count(),
+        platform=jax.devices()[0].platform,
+        devices=n_devices,
         m=len(edges),
         k=k,
         z=z,
@@ -83,11 +83,12 @@ def _measure(args) -> dict:
 
 
 def _spawn(n_devices: int, args) -> dict:
-    """Run `--in-process` in a subprocess pinned to n_devices fake devices."""
+    """CPU rehearsal: measure N in a child with N virtual host devices."""
     cmd = [
-        sys.executable, "-m", "benchmarks.bench_scaling", "--in-process",
+        sys.executable, "-m", "benchmarks.bench_scaling",
+        "--devices", str(n_devices),
         "--graph", args.graph, "--scale", str(args.scale),
-        "--k", str(args.k), "--z", str(args.z), "--spread", str(args.spread),
+        "--k", str(args.k), "--spread", str(args.spread),
         "--window", str(args.window), "--iters", str(args.iters),
     ]
     env = dict(os.environ)
@@ -113,39 +114,40 @@ def main(argv=None):
     ap.add_argument("--graph", default="brain_like")
     ap.add_argument("--scale", type=float, default=0.02)
     ap.add_argument("--k", type=int, default=16)
-    ap.add_argument("--z", type=int, default=4, help="partitioner instances")
     ap.add_argument("--spread", type=int, default=0,
                     help="partitions per instance (0 = k/z disjoint blocks)")
     ap.add_argument("--window", type=int, default=64)
     ap.add_argument("--iters", type=int, default=10, help="engine supersteps")
     ap.add_argument("--devices", default="1,2,4,8",
-                    help="comma-separated fake-device counts to sweep")
+                    help="comma-separated device counts to sweep")
     ap.add_argument("--smoke", action="store_true",
                     help="CI-size: tiny graph, N in {1,2}")
-    ap.add_argument("--in-process", action="store_true",
-                    help="measure at THIS process's device count (set "
-                         "XLA_FLAGS yourself) instead of spawning the sweep")
+    ap.add_argument("--rehearse", action="store_true",
+                    help="CPU rehearsal: one child process per N with N "
+                         "virtual CPU devices (never on a TPU host)")
     ap.add_argument("--json", default=None)
     args = ap.parse_args(argv)
     if args.smoke:
-        args.scale, args.k, args.z, args.window, args.iters = 0.008, 8, 4, 16, 4
+        args.scale, args.k, args.window, args.iters = 0.008, 8, 16, 4
         if args.devices == "1,2,4,8":
             args.devices = "1,2"
+    sweep = [int(x) for x in args.devices.split(",") if x]
 
-    if args.in_process:
-        row = _measure(args)
-        print(f"{_JSON_MARK}{json.dumps(row)}")
-        rows = [row]
+    if args.rehearse:
+        rows = [_spawn(n_dev, args) for n_dev in sweep]
     else:
-        rows = []
-        print("devices,backend,n_shards,t_partition_batched_s,"
-              "t_partition_loop_s,partition_speedup,supersteps_per_s")
-        for n_dev in [int(x) for x in args.devices.split(",") if x]:
-            r = _spawn(n_dev, args)
-            rows.append(r)
-            print(f"{r['devices']},{r['backend']},{r['n_shards']},"
-                  f"{r['t_partition_batched_s']},{r['t_partition_loop_s']},"
-                  f"{r['partition_speedup']},{r['supersteps_per_s']}")
+        import jax
+
+        rows = [_measure(args, n_dev) for n_dev in sweep
+                if n_dev <= jax.device_count()]
+    print("platform,devices,backend,n_shards,t_partition_batched_s,"
+          "t_partition_loop_s,partition_speedup,supersteps_per_s")
+    for r in rows:
+        print(f"{r['platform']},{r['devices']},{r['backend']},{r['n_shards']},"
+              f"{r['t_partition_batched_s']},{r['t_partition_loop_s']},"
+              f"{r['partition_speedup']},{r['supersteps_per_s']}")
+        if not args.rehearse:
+            print(f"{_JSON_MARK}{json.dumps(r)}")
     if args.json:
         json.dump(rows, open(args.json, "w"), indent=1)
     return rows

@@ -103,7 +103,15 @@ def main(argv=None):
         ap.error("--full and --smoke are mutually exclusive")
     scale = 0.08 if args.full else 0.012
 
+    import jax
+
+    from repro import compat
     from repro.obs import Tracer, resolve_tracer
+
+    compat.enable_compile_cache()
+    # Only a CPU host rehearses device scaling in child processes; on a TPU
+    # host this process holds the chip and every bench measures in it.
+    rehearse = [] if jax.default_backend() == "tpu" else ["--rehearse"]
 
     tr = resolve_tracer(Tracer() if args.trace else None)
 
@@ -147,7 +155,7 @@ def main(argv=None):
             lambda: bench_spotlight.main(["--scale", "0.01", *k, "--z", "4"]))
         results["scaling"] = sec(
             "\n=== multi-device scaling (smoke: N in {1,2}) ===", "scaling",
-            lambda: bench_scaling.main(["--smoke"]))
+            lambda: bench_scaling.main(["--smoke", *rehearse]))
         results["io"] = sec(
             "\n=== out-of-core I/O: ingest + ring-buffer partitioning (smoke) ===",
             "io", lambda: bench_io.main(["--smoke"]))
@@ -178,7 +186,7 @@ def main(argv=None):
             "\n=== multi-device scaling: batched spotlight + engine vs N ===",
             "scaling",
             lambda: bench_scaling.main(
-                ["--scale", str(scale / 2), "--devices", "1,2,4,8"]))
+                ["--scale", str(scale / 2), "--devices", "1,2,4,8", *rehearse]))
         results["io"] = sec(
             "\n=== out-of-core I/O: ingest MB/s + file vs in-memory wall ===",
             "io", lambda: bench_io.main(["--scale", str(scale)]))

@@ -1,149 +1,87 @@
-"""repro.compat — JAX version-portability layer.
+"""repro.compat — the JAX surfaces the repo builds on, in one place.
 
-The repo must run on whatever JAX the container ships, and the surfaces we
-depend on have moved between releases:
+The one target is the installed JAX (0.9.x). What the repo takes from it
+with a fixed policy rather than the library default:
 
-  * ``shard_map``: new JAX exposes ``jax.shard_map`` with a ``check_vma``
-    kwarg; 0.4.x has ``jax.experimental.shard_map.shard_map`` with
-    ``check_rep`` instead. :func:`shard_map` resolves the callable once at
-    import and adapts the replication-check kwarg by signature inspection.
-  * ``make_mesh``: newer convenience constructor; older JAX only has
-    ``jax.sharding.Mesh``. :func:`make_mesh` prefers the former and falls
-    back to reshaping the device list into a ``Mesh`` by hand.
-  * Pallas: the kernels in :mod:`repro.kernels` lower for real only on TPU;
-    elsewhere they run in interpret mode — and on installs where
-    ``jax.experimental.pallas`` is absent entirely they must be skipped in
-    favour of the XLA reference ops. :data:`HAS_PALLAS` /
-    :func:`pallas_interpret` are the probe the kernel wrappers consult.
+  * ``shard_map``: :func:`shard_map` is ``jax.shard_map`` with the
+    replication check (``check_vma``) under one repo-wide keyword.
+  * ``make_mesh``: ``jax.make_mesh`` defaults to ``AxisType.Explicit`` axes,
+    on which gathers from a sharded operand raise ``ShardingTypeError``.
+    :func:`make_mesh` builds ``Auto`` axes, which every mesh here assumes.
+  * Pallas: :func:`has_pallas_cpu_lowering` is the probe the kernel tier
+    ladder consults for the ``pallas-cpu`` tier.
+  * :func:`enable_compile_cache` points JAX's persistent compilation cache
+    at a fixed directory; each entry point calls it at the top of ``main``.
 
-Everything engine/kernel/launch code needs from JAX's moving surface goes
-through here; nothing else in the repo should touch
-``jax.experimental.shard_map`` or version-sniff JAX directly.
+Engine, kernel and launch code reach these surfaces through here.
 """
 from __future__ import annotations
 
-import inspect
-from typing import Callable, Sequence
+import os
+from pathlib import Path
+from typing import Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 __all__ = [
     "shard_map",
-    "SHARD_MAP_ORIGIN",
-    "REP_CHECK_KWARG",
     "make_mesh",
-    "HAS_PALLAS",
-    "HAS_PALLAS_TPU",
-    "HAS_PREFETCH_GRID",
-    "has_pallas",
+    "enable_compile_cache",
+    "COMPILE_CACHE_DIR",
     "has_pallas_cpu_lowering",
-    "pallas_interpret",
-    "pallas",
-    "pallas_tpu",
 ]
 
 
-# ----------------------------------------------------------------------------
-# shard_map resolution
-# ----------------------------------------------------------------------------
-
-def _resolve_shard_map() -> tuple[Callable, str]:
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn, "jax.shard_map"
-    from jax.experimental.shard_map import shard_map as fn  # JAX <= 0.4.x
-    return fn, "jax.experimental.shard_map.shard_map"
-
-
-_SHARD_MAP_RAW, SHARD_MAP_ORIGIN = _resolve_shard_map()
-
-
-def _rep_check_kwarg() -> str | None:
-    try:
-        params = inspect.signature(_SHARD_MAP_RAW).parameters
-    except (TypeError, ValueError):  # e.g. C-accelerated wrapper
-        return None
-    for name in ("check_vma", "check_rep"):
-        if name in params:
-            return name
-    return None
-
-
-REP_CHECK_KWARG = _rep_check_kwarg()
-
-
 def shard_map(f, mesh, in_specs, out_specs, check_replication: bool = True):
-    """Version-portable ``shard_map``.
-
-    ``check_replication`` maps onto whichever of ``check_vma`` (new JAX) /
-    ``check_rep`` (0.4.x) the installed version accepts, and is dropped
-    silently if neither exists.
-    """
-    kwargs = {}
-    if REP_CHECK_KWARG is not None:
-        kwargs[REP_CHECK_KWARG] = check_replication
-    return _SHARD_MAP_RAW(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
+    """``jax.shard_map`` with the replication check as ``check_replication``."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_replication,
     )
 
-
-# ----------------------------------------------------------------------------
-# Mesh construction
-# ----------------------------------------------------------------------------
 
 def make_mesh(
     axis_shapes: Sequence[int],
     axis_names: Sequence[str],
     devices: Sequence | np.ndarray | None = None,
 ) -> Mesh:
-    """``jax.make_mesh`` when available, else a hand-rolled ``Mesh``."""
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
     shape = tuple(int(s) for s in axis_shapes)
-    mk = getattr(jax, "make_mesh", None)
-    if mk is not None:
-        try:
-            return mk(shape, tuple(axis_names), devices=devices)
-        except TypeError:  # very old make_mesh without the devices kwarg
-            if devices is None:
-                return mk(shape, tuple(axis_names))
-    n = int(np.prod(shape))
-    devs = np.asarray(jax.devices()[:n] if devices is None else devices)
-    if devs.size != n:
-        raise ValueError(f"need {n} devices for mesh {shape}, got {devs.size}")
-    return Mesh(devs.reshape(shape), tuple(axis_names))
+    return jax.make_mesh(
+        shape, tuple(axis_names), devices=devices,
+        axis_types=(AxisType.Auto,) * len(shape),
+    )
 
 
 # ----------------------------------------------------------------------------
-# Pallas availability probe
+# Persistent compilation cache
 # ----------------------------------------------------------------------------
 
-try:
-    from jax.experimental import pallas  # noqa: F401
-    HAS_PALLAS = True
-except Exception:  # pragma: no cover - missing/broken pallas install
-    pallas = None
-    HAS_PALLAS = False
-
-try:
-    from jax.experimental.pallas import tpu as pallas_tpu  # noqa: F401
-    HAS_PALLAS_TPU = True
-except Exception:  # pragma: no cover
-    pallas_tpu = None
-    HAS_PALLAS_TPU = False
-
-# Deprecated upstream; segment_sum's ragged-block steering still needs it.
-HAS_PREFETCH_GRID = HAS_PALLAS_TPU and hasattr(pallas_tpu, "PrefetchScalarGridSpec")
+# <checkout>/.jax_cache: fixed, because the directory is part of what a later
+# process must find again (a temp or per-run path never hits).
+COMPILE_CACHE_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
 
 
-def has_pallas(require_tpu_support: bool = False) -> bool:
-    return HAS_PALLAS_TPU if require_tpu_support else HAS_PALLAS
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR``, when set, is left in charge (JAX reads
+    it itself) and nothing else is configured. Otherwise the cache lives at
+    :data:`COMPILE_CACHE_DIR`. Entry points call this at the top of
+    ``main``; importing ``repro`` never does, so tests keep JAX's defaults.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
-def pallas_interpret() -> bool:
-    """True when Pallas kernels must run in interpret mode (non-TPU backend)."""
-    return jax.default_backend() != "tpu"
-
+# ----------------------------------------------------------------------------
+# Pallas CPU lowering probe
+# ----------------------------------------------------------------------------
 
 # Lazy: probing requires compiling a (tiny) kernel, so it must not run at
 # import time. None = not probed yet.
@@ -153,7 +91,7 @@ _PALLAS_CPU_LOWERING: bool | None = None
 def has_pallas_cpu_lowering() -> bool:
     """True when this JAX can *lower* (not interpret) Pallas on the CPU backend.
 
-    Newer JAX grows a real CPU lowering path for ``pallas_call``; 0.4.x raises
+    Where JAX has no CPU lowering path for ``pallas_call`` it raises
     ``Only interpret mode is supported on CPU backend``. The kernel tier
     resolver (:mod:`repro.kernels.ops`) consults this once: when it is False
     the ``pallas-cpu`` tier is simply unavailable and dispatch lands on XLA —
@@ -164,10 +102,11 @@ def has_pallas_cpu_lowering() -> bool:
     global _PALLAS_CPU_LOWERING
     if _PALLAS_CPU_LOWERING is not None:
         return _PALLAS_CPU_LOWERING
-    if not HAS_PALLAS or jax.default_backend() == "tpu":
+    if jax.default_backend() == "tpu":
         _PALLAS_CPU_LOWERING = False
         return False
     import jax.numpy as jnp
+    from jax.experimental import pallas
 
     def _copy(x_ref, o_ref):
         o_ref[...] = x_ref[...]
@@ -180,6 +119,6 @@ def has_pallas_cpu_lowering() -> bool:
         )(jnp.zeros((8, 128), jnp.float32))
         jax.block_until_ready(out)
         _PALLAS_CPU_LOWERING = True
-    except Exception:  # ValueError on 0.4.x; be permissive about the message
+    except Exception:  # the message varies; any failure means no lowering
         _PALLAS_CPU_LOWERING = False
     return _PALLAS_CPU_LOWERING

@@ -3,6 +3,7 @@ from repro.engine.partitioned import PartitionedGraph, build_partitioned_graph
 from repro.engine.gas import engine_mesh, make_superstep
 from repro.engine.algorithms import (
     pagerank,
+    pagerank_superstep,
     label_propagation,
     coloring,
     triangle_count,
@@ -21,6 +22,7 @@ __all__ = [
     "engine_mesh",
     "make_superstep",
     "pagerank",
+    "pagerank_superstep",
     "label_propagation",
     "coloring",
     "triangle_count",

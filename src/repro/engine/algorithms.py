@@ -28,25 +28,47 @@ from jax.sharding import Mesh
 from repro.engine.gas import engine_mesh, make_superstep
 from repro.engine.partitioned import PartitionedGraph
 
-__all__ = ["pagerank", "label_propagation", "coloring", "triangle_count"]
+__all__ = [
+    "pagerank", "pagerank_superstep", "label_propagation", "coloring",
+    "triangle_count",
+]
 
 
-def pagerank(
-    g: PartitionedGraph, iters: int = 20, damping: float = 0.85,
-    mesh: Mesh | None = None, trace=None,
-) -> Tuple[np.ndarray, dict]:
+def pagerank_superstep(
+    g: PartitionedGraph, damping: float = 0.85, mesh: Mesh | None = None,
+    trace=None,
+):
+    """PageRank's jitted superstep and its initial state, ``(step, x0)``.
+
+    ``x ← (1−d)/V + d·Σ_{nbrs} x_u / deg(u)`` with mass pushed both ways
+    along every (undirected) edge. Exposed so a caller can time supersteps
+    with the compile outside the window; :func:`pagerank` iterates it.
+    """
     mesh = mesh or engine_mesh(k=g.k)
     v = g.num_vertices
+    msg, apply = pagerank_update(v, damping)
+    step = make_superstep(g, msg, apply, mesh, trace=trace)
+    return step, jnp.full((v, 1), 1.0 / v, jnp.float32)
+
+
+def pagerank_update(num_vertices: int, damping: float = 0.85):
+    """PageRank's ``(msg_fn, apply_fn)`` pair for :func:`make_superstep`."""
 
     def msg(x_u, x_v, deg_u, deg_v):
         # Push current rank mass along both directions (undirected).
         return x_u / jnp.maximum(deg_u, 1)[:, None], x_v / jnp.maximum(deg_v, 1)[:, None]
 
     def apply(state, synced, degrees):
-        return (1.0 - damping) / v + damping * synced
+        return (1.0 - damping) / num_vertices + damping * synced
 
-    step = make_superstep(g, msg, apply, mesh, trace=trace)
-    state = jnp.full((v, 1), 1.0 / v, jnp.float32)
+    return msg, apply
+
+
+def pagerank(
+    g: PartitionedGraph, iters: int = 20, damping: float = 0.85,
+    mesh: Mesh | None = None, trace=None,
+) -> Tuple[np.ndarray, dict]:
+    step, state = pagerank_superstep(g, damping, mesh, trace)
     for _ in range(iters):
         state = step(state)
     return np.asarray(state[:, 0]), dict(supersteps=iters, msg_width=1)

@@ -17,9 +17,8 @@ shrinks with replication degree when the accumulator is masked to local
 replicas, which we do (zeros compress under sparse collectives; on GPU/IB
 clusters the mask is what a ragged all-to-all would send).
 
-All JAX version-variant surfaces (`shard_map` location and its
-replication-check kwarg, `make_mesh`) are reached through `repro.compat`, so
-the engine runs unchanged on 0.4.x and current JAX, single- or multi-device.
+`shard_map` and the mesh come from `repro.compat` (Auto mesh axes), so the
+engine runs unchanged on one device or many.
 """
 from __future__ import annotations
 
@@ -35,7 +34,7 @@ from repro import compat
 from repro.engine.partitioned import PartitionedGraph
 from repro.obs import resolve_tracer
 
-__all__ = ["make_superstep", "engine_mesh", "gather_local"]
+__all__ = ["make_superstep", "superstep_program", "engine_mesh", "gather_local"]
 
 
 def engine_mesh(n_devices: int | None = None, k: int | None = None) -> Mesh:
@@ -90,6 +89,45 @@ def gather_local(
         return acc
 
     return jax.vmap(one_partition)(edges, evalid)
+
+
+def superstep_program(
+    mesh: Mesh,
+    msg_fn: Callable,
+    apply_fn: Callable,  # (state, synced_acc, degrees) -> state
+    num_vertices: int,
+    combine: str = "add",
+):
+    """The jitted superstep over ``mesh``:
+    ``(state, edges, evalid, replicas_t, degrees) -> state``.
+
+    ``edges`` (kp, E, 2), ``evalid`` (kp, E) and ``replicas_t`` (kp, V) are
+    sharded over ``parts``; ``state`` (V, d) and ``degrees`` (V,) are
+    replicated. Gather runs per device, then the replica-masked accumulators
+    are combined across ``parts`` (psum for ``add``, pmin for ``min``).
+    """
+    if combine not in ("add", "min"):
+        raise ValueError(combine)
+
+    def step(state, edges, evalid, replicas_t, degrees):
+        acc = gather_local(
+            edges, evalid, state, degrees, msg_fn, num_vertices, agg=combine
+        )
+        if combine == "add":
+            local = (acc * replicas_t[:, :, None]).sum(axis=0)  # mask to replicas
+            synced = jax.lax.psum(local, "parts")
+        else:
+            local = jnp.where(replicas_t[:, :, None] > 0, acc, BIG).min(axis=0)
+            synced = jax.lax.pmin(local, "parts")
+        return apply_fn(state, synced, degrees)
+
+    return jax.jit(compat.shard_map(
+        step,
+        mesh=mesh,
+        in_specs=(P(), P("parts"), P("parts"), P("parts"), P()),
+        out_specs=P(),
+        check_replication=False,
+    ))
 
 
 def make_superstep(
@@ -160,40 +198,24 @@ def make_superstep(
         evalid_d = evalid_d[perm]
         repl_t = repl_t[perm]
 
-    def step(state, edges, evalid, replicas_t, degrees):
-        acc = gather_local(edges, evalid, state, degrees, msg_fn, v, agg=combine)
-        if combine == "add":
-            local = (acc * replicas_t[:, :, None]).sum(axis=0)  # mask to replicas
-            synced = jax.lax.psum(local, "parts")
-        elif combine == "min":
-            local = jnp.where(replicas_t[:, :, None] > 0, acc, BIG).min(axis=0)
-            synced = jax.lax.pmin(local, "parts")
-        else:
-            raise ValueError(combine)
-        return apply_fn(state, synced, degrees)
-
-    shard_step = compat.shard_map(
-        step,
-        mesh=mesh,
-        in_specs=(P(), P("parts"), P("parts"), P("parts"), P()),
-        out_specs=P(),
-        check_replication=False,
+    program = superstep_program(mesh, msg_fn, apply_fn, v, combine)
+    # The graph lives where the program reads it: each device holds its own
+    # slab, and the arrays are arguments of the jitted step, not constants
+    # embedded in it or resharded from one device on every call.
+    parts = NamedSharding(mesh, P("parts"))
+    edges_d, evalid_d, repl_t = (
+        jax.device_put(x, parts) for x in (edges_d, evalid_d, repl_t)
     )
+    degrees = jax.device_put(g.degrees, NamedSharding(mesh, P()))
 
-    @jax.jit
     def superstep(state):
-        return shard_step(state, edges_d, evalid_d, repl_t, g.degrees)
+        return program(state, edges_d, evalid_d, repl_t, degrees)
 
     slab_occupancy = tuple(int(c) for c in occupancy)
     tr = resolve_tracer(trace)
     if not tr.enabled:
-        # jit-wrapped callables reject attribute assignment; a plain
-        # closure carries the placement metadata either way.
-        def plain_superstep(state):
-            return superstep(state)
-
-        plain_superstep.slab_occupancy = slab_occupancy
-        return plain_superstep
+        superstep.slab_occupancy = slab_occupancy
+        return superstep
 
     # Tracing wraps the jitted call from the host side: the span covers
     # dispatch only (no block_until_ready, no added sync) and lives outside

@@ -70,14 +70,10 @@ EDGE_IO_COST_S = 1.0e-6
 
 def _score_cost_s() -> float:
     """Per-score cost at the measured kernel tier, else the calibrated
-    constant. Import is lazy/defensive: the model must keep working on
-    installs where the kernels package cannot load."""
-    try:
-        from repro.kernels.ops import measured_score_cost_s
+    constant."""
+    from repro.kernels.ops import measured_score_cost_s
 
-        measured = measured_score_cost_s()
-    except Exception:
-        measured = None
+    measured = measured_score_cost_s()
     return SCORE_COST_S if measured is None else measured
 # Host→device stream-buffer bandwidth (PCIe-gen4-class x16 sustained). The
 # scan drivers count every byte they ship (`h2d_bytes` in partition stats —

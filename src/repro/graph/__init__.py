@@ -2,6 +2,7 @@
 from repro.graph.generate import (
     barabasi_albert,
     erdos_renyi,
+    kronecker,
     rmat,
     watts_strogatz,
     make_graph,
@@ -22,6 +23,7 @@ from repro.graph.metrics import (
 __all__ = [
     "barabasi_albert",
     "erdos_renyi",
+    "kronecker",
     "rmat",
     "watts_strogatz",
     "make_graph",

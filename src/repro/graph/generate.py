@@ -22,6 +22,7 @@ import numpy as np
 
 __all__ = [
     "rmat",
+    "kronecker",
     "barabasi_albert",
     "watts_strogatz",
     "erdos_renyi",
@@ -73,6 +74,61 @@ def rmat(
     edges = np.stack([u, v], axis=1).astype(np.int32)
     edges = _dedupe(edges, n)[:m]
     return edges, n
+
+
+def kronecker(
+    scale: int,
+    edge_factor: int = 16,
+    a: float = 0.57,
+    b: float = 0.19,
+    c: float = 0.19,
+    seed: int = 0,
+    max_edges: int | None = None,
+) -> tuple[np.ndarray, int]:
+    """Graph500 Kronecker graph, in file order (stable sort by source).
+
+    As the Graph500 generator: ``edge_factor · 2**scale`` edges, each
+    descending ``scale`` levels of the initiator ``[[a, b], [c, d]]``, then
+    every vertex id relabeled by one random permutation. Self-loops and
+    repeated edges are kept, as in the specification's edge list.
+
+    ``max_edges`` returns only the first ``max_edges`` rows of that file.
+    Edges are drawn in fixed-size chunks from one stream and only sources
+    below a threshold are kept, so the cut equals the prefix of the full
+    graph without ever holding the full edge array.
+    """
+    n = 1 << scale
+    m = edge_factor * n
+    want = m if max_edges is None else min(int(max_edges), m)
+    perm = np.random.default_rng([seed, 1]).permutation(n).astype(np.int64)
+    ab, abc = a + b, a + b + c
+    chunk = 1 << 22
+    # Expected edges with source < thr is m·thr/n; keep 4x what is needed
+    # and widen (from the same streams) in the rare case that falls short.
+    thr = n if want >= m else min(n, -(-4 * want * n // m))
+    while True:
+        rng = np.random.default_rng([seed, 0])
+        kept = []
+        for start in range(0, m, chunk):
+            cnt = min(chunk, m - start)
+            u = np.zeros(cnt, np.int64)
+            v = np.zeros(cnt, np.int64)
+            for _ in range(scale):
+                r = rng.random(cnt, dtype=np.float32)
+                ub = r >= ab
+                u <<= 1
+                u |= ub
+                v <<= 1
+                v |= ((r >= a) & ~ub) | (r >= abc)
+            u, v = perm[u], perm[v]
+            keep = u < thr
+            kept.append(np.stack([u[keep], v[keep]], axis=1).astype(np.int32))
+        edges = np.concatenate(kept)
+        if len(edges) >= want or thr >= n:
+            break
+        thr = min(n, 2 * thr)
+    edges = edges[np.argsort(edges[:, 0], kind="stable")]
+    return edges[:want], n
 
 
 def barabasi_albert(n: int, m_per_node: int, seed: int = 0) -> tuple[np.ndarray, int]:

@@ -20,9 +20,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
-# TPU scratch memory spaces are unused under interpret=True on CPU; both are
-# None on installs without pallas (ops.py then routes to the XLA reference).
-from repro.compat import pallas as pl, pallas_tpu as pltpu
+# TPU scratch memory spaces are unused under interpret=True on CPU.
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 BQ = 128
@@ -103,8 +103,6 @@ def flash_attention_pallas(
         assert causal, "non-causal flash requires Tk divisible by BK"
 
     grid = (b, hq, tq_pad // BQ, tk_pad // BK)
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError("pallas TPU scratch unavailable")
     scratch_shapes = [
         pltpu.VMEM((BQ, dh), jnp.float32),
         pltpu.VMEM((BQ, 1), jnp.float32),

@@ -7,8 +7,8 @@ old 'auto'/'pallas'/'ref' impl switch:
   * ``pallas-cpu`` — `pl.pallas_call` lowered through JAX's CPU Pallas
     lowering path, on installs whose JAX supports it (probed once in
     `repro.compat.has_pallas_cpu_lowering`). Never interpret mode.
-  * ``xla``        — the XLA fallbacks (`segment_sum_xla` / the pure-jnp
-    oracles in `kernels/ref.py`). Always available.
+  * ``xla``        — the pure-jnp oracles in `kernels/ref.py`. Always
+    available.
   * ``interpret``  — Pallas interpret mode. This is an explicit DEBUG flag
     (``tier='interpret'`` or ``$ADWISE_KERNEL_TIER=interpret``); the
     resolver never lands on it by itself, so the default path is never
@@ -21,9 +21,9 @@ version) in a small on-disk autotune table (see :func:`autotune_cache_path`;
 override/escape hatch: force ``xla`` for bit-stable CI runs, ``interpret``
 to step through a kernel.
 
-Pallas availability is probed through `repro.compat`: on installs without
-`jax.experimental.pallas` the pallas tiers are simply absent and every op
-runs its XLA tier — callers never crash on import or dispatch.
+On a TPU backend nothing falls back: a pallas-tpu candidate that fails
+during autotune, or an explicit tier that cannot run, raises — so a wall
+reported for a kernel is always that kernel's.
 """
 from __future__ import annotations
 
@@ -98,20 +98,9 @@ def available_tiers(op: str) -> tuple[str, ...]:
     if op not in _OPS:
         raise ValueError(f"unknown op {op!r}; known: {_OPS}")
     tiers: list[str] = []
-    needs_tpu = op in _NEEDS_TPU_SUPPORT
-    needs_prefetch = op == "segment_sum"
-    if (
-        jax.default_backend() == "tpu"
-        and compat.has_pallas(needs_tpu)
-        and (not needs_prefetch or compat.HAS_PREFETCH_GRID)
-    ):
+    if jax.default_backend() == "tpu":
         tiers.append("pallas-tpu")
-    if (
-        jax.default_backend() != "tpu"
-        and not needs_tpu
-        and compat.has_pallas()
-        and compat.has_pallas_cpu_lowering()
-    ):
+    elif op not in _NEEDS_TPU_SUPPORT and compat.has_pallas_cpu_lowering():
         tiers.append("pallas-cpu")
     tiers.append("xla")
     return tuple(tiers)
@@ -207,7 +196,11 @@ def autotune_record(op: str, bucket: str, candidates: dict) -> dict:
     for tier, thunk in candidates.items():
         try:
             walls[tier] = _time_call(thunk)
-        except Exception as e:  # a candidate that errors just loses
+        except Exception as e:
+            if jax.default_backend() == "tpu":
+                # On the chip a kernel that fails to lower or run is a bug:
+                # timing the XLA tier in its place would hide it.
+                raise
             warnings.warn(
                 f"{op}: tier '{tier}' failed during autotune ({e!r}); "
                 "excluded from selection",
@@ -249,7 +242,8 @@ def resolve_tier(
     and caching the verdict when more than one lowered tier is available —
     and finally the static preference order :data:`TIERS`. ``'interpret'``
     is honoured only as an explicit request (debug); an explicit tier that
-    cannot run on this install degrades loudly to the best available one.
+    cannot run on this install degrades loudly to the best available one,
+    except on a TPU backend, where it raises.
     ``'ref'`` is accepted as a legacy alias of ``'xla'``.
     """
     if tier == "ref":  # legacy alias from the impl= era
@@ -261,11 +255,7 @@ def resolve_tier(
             tier = env
     if tier != "auto":
         if tier == INTERPRET_TIER:
-            if compat.has_pallas(op in _NEEDS_TPU_SUPPORT):
-                return INTERPRET_TIER
-            return _downgrade(
-                op, tier, "xla", "this install has no pallas to interpret"
-            )
+            return INTERPRET_TIER
         if tier not in TIERS:
             raise ValueError(
                 f"{op}: unknown kernel tier {tier!r}; expected one of "
@@ -273,6 +263,11 @@ def resolve_tier(
             )
         if tier in avail:
             return tier
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                f"{op}: tier {tier!r} cannot run on this TPU backend "
+                f"(runnable: {avail})"
+            )
         return _downgrade(
             op, tier, avail[0], "this install cannot lower it"
         )
@@ -368,9 +363,7 @@ def segment_sum_sorted(
     The pallas tiers run the blocked-CSR kernel over the
     `csr_block_layout` padding; the ``xla`` tier is the plain
     `jax.ops.segment_sum` reference over the raw sorted ids (no layout
-    cost). A pallas request on an install without `PrefetchScalarGridSpec`
-    still routes through the blocked entry point, which falls back to its
-    `segment_sum_xla` fast path with a RuntimeWarning.
+    cost).
 
     Every tier accumulates and returns fp32 regardless of input dtype (the
     blocked kernel's MXU-style mixed precision) — switching tiers never

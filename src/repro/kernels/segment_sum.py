@@ -18,14 +18,12 @@ ragged-block pattern.
 """
 from __future__ import annotations
 
-import functools
-import warnings
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import pallas as pl, pallas_tpu as pltpu  # None when absent
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 SB = 128  # segment (output row) block
 EB = 512  # edge chunk
@@ -95,14 +93,13 @@ def segment_sum_xla(
     chunk_ptr: jax.Array,  # (n_sblocks,) int32
     num_segments: int,
 ) -> jax.Array:
-    """`jax.ops.segment_sum` fast path over the same blocked CSR layout.
+    """`jax.ops.segment_sum` over the same blocked CSR layout.
 
-    Used when pallas-TPU's (deprecated-upstream) `PrefetchScalarGridSpec` is
-    absent: global destination ids are reconstructed from the layout
-    (block-of-chunk × SB + local id) and handed to XLA's segment sum, so
-    callers of the blocked kernel keep working — and fast — on installs
-    where the Pallas grid cannot be built. Padding rows carry zero data, so
-    they contribute nothing wherever their reconstructed id lands.
+    The layout's reference, independent of Pallas: global destination ids
+    are reconstructed from the layout (block-of-chunk × SB + local id) and
+    handed to XLA's segment sum, so a wrong `csr_block_layout` shows here
+    before it shows in the kernel. Padding rows carry zero data, so they
+    contribute nothing wherever their reconstructed id lands.
     """
     e_pad, _ = data_padded.shape
     n_sblocks = chunk_ptr.shape[0]
@@ -130,12 +127,13 @@ def _kernel(chunk_ptr_ref, nchunks_ref, loc_ref, data_ref, out_ref):
 
     @pl.when(c < nchunks_ref[b])
     def _acc():
-        loc = loc_ref[0, :]  # (EB,) int32 local ids; padding rows have data==0
-        onehot = (loc[:, None] == jax.lax.broadcasted_iota(jnp.int32, (EB, SB), 1)).astype(
-            jnp.float32
-        )
+        # (1, EB) local ids; padding rows have data == 0. The one-hot is
+        # built already transposed, (SB, EB), so the MXU takes it as is.
+        onehot_t = (
+            jax.lax.broadcasted_iota(jnp.int32, (SB, EB), 0) == loc_ref[...]
+        ).astype(jnp.float32)
         contrib = jax.lax.dot(
-            onehot.T, data_ref[...], preferred_element_type=jnp.float32
+            onehot_t, data_ref[...], preferred_element_type=jnp.float32
         )
         out_ref[...] += contrib
 
@@ -155,18 +153,6 @@ def segment_sum_pallas(
     ``interpret=True`` is a debug flag only — tier dispatch (including the
     decision to run this kernel at all) lives in ``ops.segment_sum_sorted``.
     """
-    if pl is None or pltpu is None or not hasattr(pltpu, "PrefetchScalarGridSpec"):
-        # Fast path (ROADMAP item): no Pallas prefetch grid on this install —
-        # compute the same blocked layout through jax.ops.segment_sum. Loud so
-        # a benchmark column labeled 'pallas' is never silently XLA numbers.
-        warnings.warn(
-            "segment_sum_pallas: PrefetchScalarGridSpec unavailable — running "
-            "the jax.ops.segment_sum fast path over the blocked layout; "
-            "reported timings are NOT pallas timings",
-            RuntimeWarning,
-            stacklevel=2,
-        )
-        return segment_sum_xla(data_padded, loc, chunk_ptr, num_segments)
     e_pad, d = data_padded.shape
     n_sblocks = chunk_ptr.shape[0]
     n_total_chunks = e_pad // EB
@@ -178,13 +164,15 @@ def segment_sum_pallas(
         return (jnp.minimum(ptr[b] + c, n_total_chunks - 1), 0)
 
     def loc_index(b, c, ptr, nch):
-        return (jnp.minimum(ptr[b] + c, n_total_chunks - 1), 0)
+        return (jnp.minimum(ptr[b] + c, n_total_chunks - 1), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_sblocks, max_chunks),
         in_specs=[
-            pl.BlockSpec((1, EB), loc_index),
+            # One chunk's ids as a (1, EB) row: a block equal to the array's
+            # last two dims, which the TPU tiling rule accepts.
+            pl.BlockSpec((None, 1, EB), loc_index),
             pl.BlockSpec((EB, d), data_index),
         ],
         out_specs=pl.BlockSpec((SB, d), lambda b, c, ptr, nch: (b, 0)),
@@ -194,5 +182,5 @@ def segment_sum_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_pad, d), jnp.float32),
         interpret=interpret,
-    )(chunk_ptr, nchunks, loc.reshape(n_total_chunks, EB), data_padded)
+    )(chunk_ptr, nchunks, loc.reshape(n_total_chunks, 1, EB), data_padded)
     return out[:num_segments] if num_segments <= s_pad else out

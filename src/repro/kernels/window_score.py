@@ -11,8 +11,12 @@ neighbourhood test is an O(W²) endpoint-match which we phrase as two
 λ·B terms, one pass over VMEM-resident window state:
 
   grid  = (W / BW,)                       one program per row tile
-  VMEM  = u,v,deg,valid (1, W) rows; replica tables (W, K);
-          balance/allowed (1, K); out tile (BW, K)
+  VMEM  = u,v,valid (1, W) rows and replica tables (W, K) for the match;
+          the tile's u,v,valid,2-Ψ (BW, 1) columns and replica rows
+          (BW, K); λ·B/allowed (1, K); out tile (BW, K)
+
+Each program's row tile arrives through its own BlockSpec, so the kernel
+never slices a loaded value (Mosaic has no value-level dynamic_slice).
 
 W and K are padded to multiples of (BW=128, 128) so matmul operands are
 MXU-aligned. Padded rows/columns carry valid=0 / allowed=0 and are masked to
@@ -25,7 +29,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.compat import pallas as pl  # None when pallas is unavailable
+from jax.experimental import pallas as pl
 
 NEG_INF = -1e30
 BW = 128  # row-tile size (MXU sublane-aligned)
@@ -33,61 +37,45 @@ LANE = 128  # lane padding for K
 
 
 def _kernel(
-    u_ref,  # (1, W) int32
-    v_ref,  # (1, W) int32
-    valid_ref,  # (1, W) int32 (0/1)
-    degu_ref,  # (1, W) int32
-    degv_ref,  # (1, W) int32
-    repu_ref,  # (W, K) f32   replica rows of u_j
-    repv_ref,  # (W, K) f32
-    bal_ref,  # (1, K) f32   λ·B(p) already folded in host wrapper? no — raw B(p)
+    u_row_ref,  # (1, W) int32    window endpoints, full width (CS match)
+    v_row_ref,  # (1, W) int32
+    valid_row_ref,  # (1, W) int32 (0/1)
+    u_ref,  # (BW, 1) int32   this tile's rows, as columns
+    v_ref,  # (BW, 1) int32
+    valid_ref,  # (BW, 1) int32 (0/1)
+    fu_ref,  # (BW, 1) f32    2 - Ψ_u
+    fv_ref,  # (BW, 1) f32    2 - Ψ_v
+    repu_ref,  # (BW, K) f32   replica rows of this tile's u
+    repv_ref,  # (BW, K) f32
+    repu_all_ref,  # (W, K) f32  replica rows of every window edge's u
+    repv_all_ref,  # (W, K) f32
+    lbal_ref,  # (1, K) f32    λ·B(p)
     allowed_ref,  # (1, K) int32
-    scal_ref,  # (1, 2) f32   [lam, max_deg]
     out_ref,  # (BW, K) f32
     *,
     use_cs: bool,
 ):
-    i = pl.program_id(0)
-    w = u_ref.shape[1]
-    u = u_ref[0, :]
-    v = v_ref[0, :]
-    valid = valid_ref[0, :]
-    lam = scal_ref[0, 0]
-    max_deg = scal_ref[0, 1]
-
-    # Row tile of this program.
-    start = i * BW
-    u_i = jax.lax.dynamic_slice(u, (start,), (BW,))
-    v_i = jax.lax.dynamic_slice(v, (start,), (BW,))
-    valid_i = jax.lax.dynamic_slice(valid, (start,), (BW,))
-    deg_u = jax.lax.dynamic_slice(degu_ref[0, :], (start,), (BW,))
-    deg_v = jax.lax.dynamic_slice(degv_ref[0, :], (start,), (BW,))
-    repu_i = jax.lax.dynamic_slice(repu_ref[...], (start, 0), (BW, repu_ref.shape[1]))
-    repv_i = jax.lax.dynamic_slice(repv_ref[...], (start, 0), (BW, repv_ref.shape[1]))
-
-    # Degree-aware replication score R (Eq. 5), Ψ_x = deg(x)/(2·maxDeg).
-    denom = 2.0 * jnp.maximum(max_deg, 1.0)
-    psi_u = deg_u.astype(jnp.float32) / denom
-    psi_v = deg_v.astype(jnp.float32) / denom
-    g = repu_i * (2.0 - psi_u)[:, None] + repv_i * (2.0 - psi_v)[:, None]
+    # Degree-aware replication score R (Eq. 5).
+    g = repu_ref[...] * fu_ref[...] + repv_ref[...] * fv_ref[...]
 
     if use_cs:
         # Window-local neighbourhood match (CS, Eq. 6) as MXU matmuls.
+        w = u_row_ref.shape[1]
+        u_i, v_i = u_ref[...], v_ref[...]
+        u, v = u_row_ref[...], v_row_ref[...]
         col = jax.lax.broadcasted_iota(jnp.int32, (BW, w), 1)
-        row_gid = jax.lax.broadcasted_iota(jnp.int32, (BW, w), 0) + start
-        keep = (valid[None, :] > 0) & (col != row_gid)
-        a = ((u[None, :] == u_i[:, None]) | (u[None, :] == v_i[:, None])) & keep
-        b = ((v[None, :] == u_i[:, None]) | (v[None, :] == v_i[:, None])) & keep
-        af = a.astype(jnp.float32)
-        bf = b.astype(jnp.float32)
-        num = jax.lax.dot(af, repv_ref[...], preferred_element_type=jnp.float32)
-        num += jax.lax.dot(bf, repu_ref[...], preferred_element_type=jnp.float32)
-        den = af.sum(axis=1) + bf.sum(axis=1)
-        g = g + num / jnp.maximum(den, 1.0)[:, None]
+        row_gid = jax.lax.broadcasted_iota(jnp.int32, (BW, w), 0) + pl.program_id(0) * BW
+        keep = (valid_row_ref[...] > 0) & (col != row_gid)
+        af = (((u == u_i) | (u == v_i)) & keep).astype(jnp.float32)
+        bf = (((v == u_i) | (v == v_i)) & keep).astype(jnp.float32)
+        num = jax.lax.dot(af, repv_all_ref[...], preferred_element_type=jnp.float32)
+        num += jax.lax.dot(bf, repu_all_ref[...], preferred_element_type=jnp.float32)
+        den = af.sum(axis=1, keepdims=True) + bf.sum(axis=1, keepdims=True)
+        g = g + num / jnp.maximum(den, 1.0)
 
     # Adaptive balance term + validity masking.
-    g = g + lam * bal_ref[0, :][None, :]
-    ok = (valid_i[:, None] > 0) & (allowed_ref[0, :][None, :] > 0)
+    g = g + lbal_ref[...]
+    ok = (valid_ref[...] > 0) & (allowed_ref[...] > 0)
     out_ref[...] = jnp.where(ok, g, NEG_INF)
 
 
@@ -112,11 +100,6 @@ def window_score_pallas(
     lowers for real and raises where the backend cannot (dispatch belongs in
     ``ops.window_score``, which resolves a runnable tier first).
     """
-    if pl is None:
-        raise RuntimeError(
-            "jax.experimental.pallas unavailable — use ops.window_score"
-            " (impl='ref'/'auto'), which falls back to the XLA oracle"
-        )
     w, k = rep_u.shape
     w_pad = -(-w // BW) * BW
     k_pad = -(-k // LANE) * LANE
@@ -124,39 +107,48 @@ def window_score_pallas(
     def pad2(x, rows, cols, fill=0):
         return jnp.pad(x, ((0, rows - x.shape[0]), (0, cols - x.shape[1])), constant_values=fill)
 
+    def pad_col(x, fill=0):
+        return jnp.pad(x, (0, w_pad - x.shape[0]), constant_values=fill)[:, None]
+
     def pad_row(x, cols, fill=0):
         return jnp.pad(x, (0, cols - x.shape[0]), constant_values=fill)[None, :]
 
-    u = pad_row(win_uv[:, 0].astype(jnp.int32), w_pad, fill=-1)
-    v = pad_row(win_uv[:, 1].astype(jnp.int32), w_pad, fill=-2)
-    valid = pad_row(win_valid.astype(jnp.int32), w_pad)
-    dgu = pad_row(deg_u.astype(jnp.int32), w_pad)
-    dgv = pad_row(deg_v.astype(jnp.int32), w_pad)
+    # Every per-row and per-column factor is formed here with the same f32
+    # ops as the oracle, so the kernel only adds and masks.
+    denom = 2.0 * jnp.maximum(max_deg, 1).astype(jnp.float32)
+    u = pad_col(win_uv[:, 0].astype(jnp.int32), fill=-1)
+    v = pad_col(win_uv[:, 1].astype(jnp.int32), fill=-2)
+    valid = pad_col(win_valid.astype(jnp.int32))
+    fu = pad_col(2.0 - deg_u.astype(jnp.float32) / denom)
+    fv = pad_col(2.0 - deg_v.astype(jnp.float32) / denom)
     ru = pad2(rep_u.astype(jnp.float32), w_pad, k_pad)
     rv = pad2(rep_v.astype(jnp.float32), w_pad, k_pad)
-    bl = pad_row(bal.astype(jnp.float32), k_pad)
+    lbal = pad_row(lam.astype(jnp.float32) * bal.astype(jnp.float32), k_pad)
     al = pad_row(allowed.astype(jnp.int32), k_pad)
-    scal = jnp.stack([lam.astype(jnp.float32), max_deg.astype(jnp.float32)])[None, :]
 
-    full_row = lambda i: (0, 0)
-    grid = (w_pad // BW,)
+    full = lambda i: (0, 0)
+    tile = lambda i: (i, 0)
     out = pl.pallas_call(
         functools.partial(_kernel, use_cs=use_cs),
-        grid=grid,
+        grid=(w_pad // BW,),
         in_specs=[
-            pl.BlockSpec((1, w_pad), full_row),  # u
-            pl.BlockSpec((1, w_pad), full_row),  # v
-            pl.BlockSpec((1, w_pad), full_row),  # valid
-            pl.BlockSpec((1, w_pad), full_row),  # deg_u
-            pl.BlockSpec((1, w_pad), full_row),  # deg_v
-            pl.BlockSpec((w_pad, k_pad), full_row),  # rep_u
-            pl.BlockSpec((w_pad, k_pad), full_row),  # rep_v
-            pl.BlockSpec((1, k_pad), full_row),  # bal
-            pl.BlockSpec((1, k_pad), full_row),  # allowed
-            pl.BlockSpec((1, 2), full_row),  # scalars
+            pl.BlockSpec((1, w_pad), full),  # u row
+            pl.BlockSpec((1, w_pad), full),  # v row
+            pl.BlockSpec((1, w_pad), full),  # valid row
+            pl.BlockSpec((BW, 1), tile),  # u tile
+            pl.BlockSpec((BW, 1), tile),  # v tile
+            pl.BlockSpec((BW, 1), tile),  # valid tile
+            pl.BlockSpec((BW, 1), tile),  # 2 - Ψ_u tile
+            pl.BlockSpec((BW, 1), tile),  # 2 - Ψ_v tile
+            pl.BlockSpec((BW, k_pad), tile),  # rep_u tile
+            pl.BlockSpec((BW, k_pad), tile),  # rep_v tile
+            pl.BlockSpec((w_pad, k_pad), full),  # rep_u, whole window
+            pl.BlockSpec((w_pad, k_pad), full),  # rep_v, whole window
+            pl.BlockSpec((1, k_pad), full),  # λ·B
+            pl.BlockSpec((1, k_pad), full),  # allowed
         ],
-        out_specs=pl.BlockSpec((BW, k_pad), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((BW, k_pad), tile),
         out_shape=jax.ShapeDtypeStruct((w_pad, k_pad), jnp.float32),
         interpret=interpret,
-    )(u, v, valid, dgu, dgv, ru, rv, bl, al, scal)
+    )(u.T, v.T, valid.T, u, v, valid, fu, fv, ru, rv, ru, rv, lbal, al)
     return out[:w, :k]

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compat
 from repro.configs import get_config
 from repro.launch import sharding as shg
 from repro.launch.mesh import make_local_mesh
@@ -28,6 +29,7 @@ def main(argv=None):
     ap.add_argument("--tp", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compat.enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compat
 from repro.checkpoint import CheckpointManager
 from repro.configs import SHAPES, get_config
 from repro.configs.base import ShapeConfig
@@ -58,6 +59,7 @@ def main(argv=None):
                     help="simulate a transient failure at this step (testing)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compat.enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -1,18 +1,5 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — smoke tests and benches must
 see the real single CPU device; only launch/dryrun.py forces 512."""
-import sys
-
-# Offline fallback: when `hypothesis` is not installed (the no-network CI
-# container), serve the vendored seeded-sampling shim under its name so the
-# property-test modules collect and run unchanged.
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    import _propcheck
-
-    sys.modules["hypothesis"] = _propcheck
-    sys.modules["hypothesis.strategies"] = _propcheck.strategies
-
 import numpy as np
 import pytest
 

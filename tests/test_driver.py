@@ -102,7 +102,7 @@ def test_driver_direct_ring_run(rmat_file):
 # ----------------------------------------------------------------------------
 
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(
     chunk=st.integers(min_value=48, max_value=500),
     wmax=st.sampled_from([4, 8]),

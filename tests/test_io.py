@@ -579,3 +579,30 @@ def test_shuffle_report_default_path(graph_file, tmp_path):
     assert rep.num_edges == len(edges)
     assert 0 < rep.max_loaded_rows <= rep.bound_rows
     assert rep.buckets >= 1 and rep.depth >= 0
+
+
+# ----------------------------------------------------------------------------
+# Graph500 Kronecker generator: the cut is the file's prefix
+# ----------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("max_edges", [1, 777, 16 * 256 - 1, 10 ** 6])
+def test_kronecker_cut_is_prefix_of_full_file(max_edges):
+    from repro.graph import kronecker
+
+    full, n = kronecker(8, seed=3)
+    cut, n_cut = kronecker(8, seed=3, max_edges=max_edges)
+    assert n == n_cut == 256 and len(full) == 16 * 256
+    np.testing.assert_array_equal(cut, full[:max_edges])
+
+
+def test_kronecker_is_graph500_shaped():
+    from repro.graph import kronecker
+
+    e, n = kronecker(10, seed=0)
+    assert n == 1024 and e.shape == (16 * 1024, 2) and e.dtype == np.int32
+    assert (e >= 0).all() and (e < n).all()
+    assert (np.diff(e[:, 0]) >= 0).all()  # file order: sorted by source
+    deg = np.bincount(e.ravel(), minlength=n)
+    assert deg.max() > 20 * deg.mean()  # the initiator's skew survives
+    assert not np.array_equal(e, kronecker(10, seed=1)[0])

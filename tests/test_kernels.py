@@ -15,37 +15,19 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro import compat
 from repro.kernels import ops
 from repro.kernels import ref as kref
 from repro.kernels.segment_sum import csr_block_layout, segment_sum_xla, EB, SB
 
-# Tier-vs-ref comparisons are meaningless if the tier silently degrades to
-# the same code as the reference — skip rather than pass vacuously.
-requires_pallas = pytest.mark.skipif(
-    not compat.has_pallas(), reason="jax.experimental.pallas unavailable")
-requires_pallas_tpu = pytest.mark.skipif(
-    not compat.has_pallas(require_tpu_support=True),
-    reason="jax.experimental.pallas.tpu unavailable")
-requires_prefetch_grid = pytest.mark.skipif(
-    not (compat.has_pallas(require_tpu_support=True) and compat.HAS_PREFETCH_GRID),
-    reason="pltpu.PrefetchScalarGridSpec unavailable")
-
-
 def _tiers_under_test(op: str) -> list:
-    """Every runnable tier, plus explicit interpret where pallas exists."""
-    tiers = list(ops.available_tiers(op))
-    if compat.has_pallas(op in ("segment_sum", "flash_attention")):
-        if op != "segment_sum" or compat.HAS_PREFETCH_GRID:
-            tiers.append(ops.INTERPRET_TIER)
-    return tiers
+    """Every runnable tier, plus the explicit interpret debug tier."""
+    return [*ops.available_tiers(op), ops.INTERPRET_TIER]
 
 
 # ----------------------------------------------------------------------------
 # window_score
 # ----------------------------------------------------------------------------
 
-@requires_pallas
 @pytest.mark.parametrize("w,k,use_cs", [
     (1, 2, True), (7, 3, True), (128, 32, True), (200, 20, True),
     (130, 64, False), (64, 5, False),
@@ -76,7 +58,6 @@ def test_window_score_shapes(w, k, use_cs):
             np.asarray(a)[mask], np.asarray(b)[mask], err_msg=f"tier={tier}")
 
 
-@requires_pallas
 @settings(max_examples=10, deadline=None)
 @given(seed=st.integers(0, 1000), w=st.integers(1, 80), k=st.integers(1, 40))
 def test_window_score_property(seed, w, k):
@@ -102,7 +83,6 @@ def test_window_score_property(seed, w, k):
 # segment_sum
 # ----------------------------------------------------------------------------
 
-@requires_prefetch_grid
 @pytest.mark.parametrize("e,d,s,dtype", [
     (10, 8, 5, np.float32), (1000, 64, 300, np.float32),
     (3000, 32, 700, np.float32), (513, 128, 129, np.float32),
@@ -125,9 +105,9 @@ def test_segment_sum_shapes(e, d, s, dtype):
     (10, 8, 5), (1000, 64, 300), (513, 16, 129), (3000, 32, 700),
 ])
 def test_segment_sum_xla_fast_path_parity(e, d, s):
-    """The no-PrefetchScalarGridSpec fast path (jax.ops.segment_sum over the
-    blocked CSR layout) must agree with the plain sorted-segment reference.
-    Runs on every install — it needs no pallas at all."""
+    """jax.ops.segment_sum over the blocked CSR layout agrees with the plain
+    sorted-segment reference: the layout itself is right, before any
+    kernel reads it. Needs no pallas at all."""
     rng = np.random.default_rng(e * 13 + d)
     seg = np.sort(rng.integers(0, s, e)).astype(np.int32)
     data = rng.normal(size=(e, d)).astype(np.float32)
@@ -141,24 +121,25 @@ def test_segment_sum_xla_fast_path_parity(e, d, s):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5)
 
 
-def test_segment_sum_pallas_falls_back_without_prefetch_grid(monkeypatch):
-    """When pallas-TPU lacks PrefetchScalarGridSpec, the blocked kernel entry
-    point must route to the XLA fast path instead of raising."""
+def test_segment_sum_pallas_interpret_matches_blocked_reference():
+    """The blocked kernel (interpret mode) and the blocked XLA reduction
+    agree on one layout, whose chunks span several segment blocks."""
     from repro.kernels import segment_sum as ss
 
-    monkeypatch.setattr(ss, "pltpu", None)
     rng = np.random.default_rng(7)
-    e, d, s = 400, 8, 100
+    e, d, s = 1400, 8, 300
     seg = np.sort(rng.integers(0, s, e)).astype(np.int32)
     data = rng.normal(size=(e, d)).astype(np.float32)
     perm, loc, chunk_ptr, nchunks, e_pad = csr_block_layout(seg, s, d)
-    gather = np.where(perm[:, None] >= 0, data[np.maximum(perm, 0)], 0.0)
-    with pytest.warns(RuntimeWarning, match="NOT pallas timings"):
-        out = ss.segment_sum_pallas(
-            jnp.asarray(gather, jnp.float32), jnp.asarray(loc),
-            jnp.asarray(chunk_ptr), jnp.asarray(nchunks), s,
-        )
-    ref = kref.segment_sum_ref(jnp.asarray(data), jnp.asarray(seg), s)
+    gather = jnp.asarray(
+        np.where(perm[:, None] >= 0, data[np.maximum(perm, 0)], 0.0),
+        jnp.float32)
+    out = ss.segment_sum_pallas(
+        gather, jnp.asarray(loc), jnp.asarray(chunk_ptr),
+        jnp.asarray(nchunks), s, max_chunks=int(nchunks.max()),
+        interpret=True,
+    )
+    ref = segment_sum_xla(gather, jnp.asarray(loc), jnp.asarray(chunk_ptr), s)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
 
@@ -178,7 +159,7 @@ def test_csr_block_layout_rejects_out_of_range_ids():
 
 
 def test_csr_block_layout_degenerate_empty_and_single_segment():
-    # m=0: an all-padding layout that the XLA fast path reduces to zeros.
+    # m=0: an all-padding layout that the blocked reduction turns to zeros.
     perm, loc, chunk_ptr, nchunks, e_pad = csr_block_layout(
         np.array([], np.int32), 300, 4)
     assert (perm == -1).all() and e_pad % EB == 0 and e_pad > 0
@@ -229,7 +210,6 @@ def test_csr_block_layout_invariants():
 # flash_attention
 # ----------------------------------------------------------------------------
 
-@requires_pallas_tpu
 @pytest.mark.parametrize("b,hq,hkv,tq,tk,dh,dtype", [
     (1, 1, 1, 8, 8, 32, np.float32),
     (2, 4, 2, 130, 130, 64, np.float32),
@@ -286,7 +266,6 @@ def test_resolve_tier_env_override(monkeypatch):
     assert ops.resolve_tier("window_score", "xla") == "xla"
 
 
-@requires_pallas
 def test_resolve_tier_interpret_is_explicit_debug_only(monkeypatch):
     monkeypatch.delenv(ops.KERNEL_TIER_ENV, raising=False)
     assert ops.resolve_tier("window_score") != ops.INTERPRET_TIER
@@ -303,6 +282,27 @@ def test_resolve_tier_unavailable_request_downgrades_loudly(monkeypatch):
     with pytest.warns(RuntimeWarning, match="NOT pallas-tpu timings"):
         got = ops.resolve_tier("window_score", "pallas-tpu")
     assert got == avail[0]
+
+
+@pytest.mark.parametrize("path", ["explicit_tier", "autotune_candidate"])
+def test_tpu_backend_never_falls_back_to_xla(monkeypatch, path):
+    """On a TPU backend a kernel that cannot run raises; it is never timed
+    or dispatched as the XLA tier in its place."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv(ops.KERNEL_TIER_ENV, raising=False)
+    ops.clear_tier_cache()
+    if path == "explicit_tier":
+        with pytest.raises(RuntimeError, match="cannot run on this TPU"):
+            ops.resolve_tier("window_score", "pallas-cpu")
+        return
+
+    def refused():
+        raise NotImplementedError("Unimplemented primitive in Pallas TPU lowering")
+
+    with pytest.raises(NotImplementedError, match="Pallas TPU lowering"):
+        ops.autotune_record("window_score", "256x32",
+                            {"pallas-tpu": refused, "xla": lambda: jnp.zeros(())})
+    assert not ops._TIER_MEMO
 
 
 def test_autotune_microbench_caches_on_disk(monkeypatch, tmp_path):

@@ -1,8 +1,6 @@
 """Multi-pass re-streaming (restream.py) + registry-wide assignment invariants.
 
-The property tests run under the vendored `tests/_propcheck.py` shim when
-`hypothesis` is absent (seeded sampling, no shrinking) — same invariants
-either way. Streams are adversarial by construction: self-loops, duplicate
+Streams in the property tests are adversarial by construction: self-loops, duplicate
 edges, star graphs (which stall the vertex-disjoint top-b pick), empty
 streams and streams shorter than the assign batch.
 """

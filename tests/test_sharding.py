@@ -7,6 +7,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import ARCH_IDS, SHAPES, get_config
 from repro.data import make_batch_spec
+from repro import compat
 from repro.launch import sharding as shg
 from repro.models import lm
 
@@ -113,7 +114,7 @@ def test_head_policy_table():
 def test_jit_with_specs_runs_on_local_mesh():
     """End-to-end: reduced arch jitted with derived shardings on 1 device."""
     cfg = get_config("qwen1.5-0.5b").reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
     params = lm.init_params(cfg, jax.random.PRNGKey(0), tp=1)
     specs = shg.param_specs(cfg, mesh, 1, params)
     shard = shg.to_shardings(mesh, specs)

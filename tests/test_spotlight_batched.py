@@ -5,9 +5,6 @@ Covers the device-parallel refactor: z instance scans as one program
 restream × spotlight composition (per-instance WarmState batches), and —
 in a subprocess with 4 fake CPU devices — the padded `parts` engine mesh
 plus the shard_map instance axis.
-
-The property tests run under the vendored `tests/_propcheck.py` shim when
-`hypothesis` is absent, as in test_restream.py.
 """
 import os
 import subprocess
