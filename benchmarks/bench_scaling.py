@@ -1,12 +1,17 @@
 """Multi-device scaling harness: batched spotlight partitioning wall and
 engine supersteps/s vs device count.
 
-Measured in this process on the real device set: for each N the spotlight
-runs z = N instances (one per device) as ONE program, shard_mapped over
-``jax.devices()[:N]``, next to the sequential ``backend="loop"`` path over
-the same instances, and PageRank runs on an engine mesh over the same N
-devices (the `parts` axis is padded inside `make_superstep`, so every N is
-valid for every k). Device counts above ``jax.device_count()`` are skipped.
+Strong scaling: every row partitions the same graph with the same ``--z``
+spotlight instances; only the devices they run on change. Measured in this
+process on the real device set: at N = 1 the z instances run as ONE vmapped
+program on one device; at N > 1 the same program is shard_mapped over the
+instance axis, next to the sequential ``backend="loop"`` path over the same
+instances, and PageRank runs on an engine mesh over the first N devices
+(the `parts` axis is padded inside `make_superstep`, so every N is valid for
+every k). shard_map spreads the instances over the largest divisor of z
+that fits the process's devices, so an N that this process cannot isolate
+(N above ``jax.device_count()``, or fewer devices than shard_map would take)
+is skipped with a note.
 
 ``--rehearse`` is the CPU rehearsal: each N runs in a child process under
 ``JAX_PLATFORMS=cpu`` with N virtual host devices (the flag is read at
@@ -25,12 +30,32 @@ import os
 import subprocess
 import sys
 import time
+from typing import Optional
 
 _JSON_MARK = "BENCH_SCALING_ROW:"
 
 
+def _skip_reason(z: int, n_devices: int) -> Optional[str]:
+    """Why this process cannot run z instances on exactly ``n_devices``
+    devices (None when it can)."""
+    import jax
+
+    from repro.core.driver import resolve_backend
+
+    have = jax.device_count()
+    if n_devices > have:
+        return f"this process has {have} device(s)"
+    if n_devices == 1:
+        return None
+    backend, shards = resolve_backend("shard_map", z)
+    if backend != "shard_map" or shards > n_devices:
+        return (f"shard_map spreads z={z} instances over "
+                f"{shards or 1} of this process's {have} devices")
+    return None
+
+
 def _measure(args, n_devices: int) -> dict:
-    """Measure on the first ``n_devices`` devices of this process."""
+    """Measure z instances on the first ``n_devices`` devices."""
     import jax
     import numpy as np
 
@@ -39,18 +64,19 @@ def _measure(args, n_devices: int) -> dict:
     from repro.graph import make_graph
 
     edges, n = make_graph(args.graph, seed=0, scale=args.scale)
-    k, z = args.k, n_devices
+    k, z = args.k, args.z
     spread = args.spread if args.spread else max(k // z, 1)
     cfg = AdwiseConfig(k=k, window_max=args.window,
                        window_init=max(1, args.window // 4))
+    batched = "vmap" if n_devices == 1 else "shard_map"
 
     def run(backend):
         return spotlight_partition(edges, n, k, z=z, spread=spread,
                                    strategy="adwise", cfg=cfg, backend=backend)
 
     # Warm both paths (compile), then time a second run of each.
-    res_b = run("batched")
-    res_b = run("batched")
+    res_b = run(batched)
+    res_b = run(batched)
     t_batched = res_b.stats["wall_time_s"]  # measured batched-program wall
     res_l = run("loop")
     res_l = run("loop")
@@ -88,7 +114,7 @@ def _spawn(n_devices: int, args) -> dict:
         sys.executable, "-m", "benchmarks.bench_scaling",
         "--devices", str(n_devices),
         "--graph", args.graph, "--scale", str(args.scale),
-        "--k", str(args.k), "--spread", str(args.spread),
+        "--k", str(args.k), "--z", str(args.z), "--spread", str(args.spread),
         "--window", str(args.window), "--iters", str(args.iters),
     ]
     env = dict(os.environ)
@@ -114,6 +140,8 @@ def main(argv=None):
     ap.add_argument("--graph", default="brain_like")
     ap.add_argument("--scale", type=float, default=0.02)
     ap.add_argument("--k", type=int, default=16)
+    ap.add_argument("--z", type=int, default=4,
+                    help="spotlight instances, the same at every N")
     ap.add_argument("--spread", type=int, default=0,
                     help="partitions per instance (0 = k/z disjoint blocks)")
     ap.add_argument("--window", type=int, default=64)
@@ -128,7 +156,7 @@ def main(argv=None):
     ap.add_argument("--json", default=None)
     args = ap.parse_args(argv)
     if args.smoke:
-        args.scale, args.k, args.window, args.iters = 0.008, 8, 16, 4
+        args.scale, args.k, args.z, args.window, args.iters = 0.008, 8, 4, 16, 4
         if args.devices == "1,2,4,8":
             args.devices = "1,2"
     sweep = [int(x) for x in args.devices.split(",") if x]
@@ -136,10 +164,13 @@ def main(argv=None):
     if args.rehearse:
         rows = [_spawn(n_dev, args) for n_dev in sweep]
     else:
-        import jax
-
-        rows = [_measure(args, n_dev) for n_dev in sweep
-                if n_dev <= jax.device_count()]
+        rows = []
+        for n_dev in sweep:
+            why = _skip_reason(args.z, n_dev)
+            if why:
+                print(f"skip N={n_dev}: {why}")
+            else:
+                rows.append(_measure(args, n_dev))
     print("platform,devices,backend,n_shards,t_partition_batched_s,"
           "t_partition_loop_s,partition_speedup,supersteps_per_s")
     for r in rows:

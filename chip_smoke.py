@@ -10,10 +10,13 @@ graph (scale 22: 4,194,304 vertices, edge factor 16) is written as a binary
 edge file, and ``repro.launch.partition.main`` partitions it with ADWISE
 out of core (``partition_file`` on the device ring), builds the engine graph
 and runs PageRank supersteps. Only the first ``--edges`` rows of the file
-are streamed (default 2**18, a 256x cut in edges, which keeps the run
-inside 20 minutes on one v5e at about a millisecond per scan step); the
-vertex count and K are not cut, so the (V+1, K) device tables have
-deployment size.
+are streamed (default: four ring scan calls' worth, 261,120 edges, a 257x
+cut in edges, which keeps the run inside 20 minutes on one v5e at about a
+millisecond per scan step; a ring call runs its full step count however
+few edges remain, so the default fills every call); the vertex count and K
+are not cut, so the (V+1, K) device tables have deployment size. Compile
+time and persistent-cache traffic are read from ``jax.monitoring`` events
+and reported apart from run time.
 
 Every check must hold: no unassigned edge, replication degree and imbalance
 equal to a host recomputation, PageRank equal (rtol 1e-4) to a numpy power
@@ -41,6 +44,7 @@ sys.path.insert(0, str(ROOT / "src"))
 # from the file in 65,536-edge chunks, then 10 PageRank supersteps.
 SCALE, K, CHUNK_EDGES, ITERS, SEED = 22, 32, 1 << 16, 10, 0
 DAMPING = 0.85
+SCAN_CALLS = 4  # default stream length, in full ring scan calls
 
 
 def log(msg: str) -> None:
@@ -58,6 +62,54 @@ def measured() -> str:
     import jax
 
     return f"measured on {jax.devices()[0].platform}"
+
+
+class CompileLog:
+    """XLA compile time and persistent-cache traffic, counted from
+    ``jax.monitoring`` events while the process runs. ``compile_s`` is the
+    backend-compile wall, which includes loading an executable from the
+    persistent cache on a hit; ``lookups`` are compiles that consulted the
+    cache, ``hits`` were served from it, ``written`` were stored to it."""
+
+    DURATION = "/jax/core/compile/backend_compile_duration"
+    EVENTS = {
+        "/jax/compilation_cache/compile_requests_use_cache": "lookups",
+        "/jax/compilation_cache/cache_hits": "hits",
+        "/jax/compilation_cache/cache_misses": "written",
+    }
+
+    def __init__(self) -> None:
+        import jax
+
+        self.counts = dict(compile_s=0.0, programs=0, lookups=0, hits=0,
+                           written=0)
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, event: str, secs: float, **_) -> None:
+        if event == self.DURATION:
+            self.counts["compile_s"] += secs
+            self.counts["programs"] += 1
+
+    def _event(self, event: str, **_) -> None:
+        if event in self.EVENTS:
+            self.counts[self.EVENTS[event]] += 1
+
+    def since(self, mark: dict) -> str:
+        d = {key: self.counts[key] - mark[key] for key in mark}
+        return (f"XLA compile or cache load {d['compile_s']:.3f} s over "
+                f"{d['programs']} programs (persistent cache: {d['lookups']} "
+                f"lookups, {d['hits']} hits, {d['written']} written)")
+
+
+def scan_steps(k: int, chunk_edges: int) -> int:
+    """Steps in every ADWISE ring scan call at this geometry."""
+    from repro.core import AdwiseConfig
+    from repro.core.driver import FileSource
+
+    return FileSource([types.SimpleNamespace(num_edges=0)],
+                      chunk_edges=chunk_edges,
+                      cfg=AdwiseConfig(k=k)).scan_steps
 
 
 # ----------------------------------------------------------------------------
@@ -121,12 +173,11 @@ def partition_args(graph: Path, run_dir: Path, k: int, chunk_edges: int,
 
 
 def run_main_path(run_dir: Path, *, scale: int, edges: int, k: int,
-                  chunk_edges: int, iters: int, seed: int) -> dict:
+                  chunk_edges: int, iters: int, seed: int,
+                  clog: CompileLog | None = None) -> dict:
     """Warm-up compile, the main partition -> PageRank run, and its checks."""
     import numpy as np
 
-    from repro.core import AdwiseConfig
-    from repro.core.driver import FileSource
     from repro.engine import build_partitioned_graph, pagerank_superstep
     from repro.graph import (
         partition_balance,
@@ -142,13 +193,18 @@ def run_main_path(run_dir: Path, *, scale: int, edges: int, k: int,
         f"~{table_mb:.0f} MB on the device; engine replica mask (K, V) "
         f"{n * k / 1e6:.0f} MB")
 
-    t0 = time.perf_counter()
-    partition_main(partition_args(warm, run_dir, k, chunk_edges, "none", 0,
-                                  "warm"))
+    clog = clog or CompileLog()
+    steps = scan_steps(k, chunk_edges)
+    mark, t0 = dict(clog.counts), time.perf_counter()
+    out = partition_main(partition_args(warm, run_dir, k, chunk_edges, "none",
+                                        0, "warm"))
     log(f"setup: compile warm-up on a {min(len(e), chunk_edges // 2)}-edge "
-        f"prefix with the same V, K, chunk: {time.perf_counter() - t0:.3f} s")
+        f"prefix with the same V, K, chunk: {time.perf_counter() - t0:.3f} s "
+        f"wall, its {out['stats']['scan_calls']} scan call(s) of {steps} "
+        "steps included")
+    log(f"setup: {clog.since(mark)}")
 
-    t0 = time.perf_counter()
+    mark, t0 = dict(clog.counts), time.perf_counter()
     out = partition_main(partition_args(path, run_dir, k, chunk_edges,
                                         "pagerank", iters, "main"))
     wall_main = time.perf_counter() - t0
@@ -158,9 +214,8 @@ def run_main_path(run_dir: Path, *, scale: int, edges: int, k: int,
     log(f"{measured()}: partition wall {t_part:.4f} s, "
         f"{m / t_part:.1f} edges/s (adwise, k={k}); "
         f"main run incl. engine build + PageRank {wall_main:.3f} s")
+    log(f"main run: {clog.since(mark)}")
     # Every ring scan call runs S steps whether or not edges remain.
-    steps = FileSource([types.SimpleNamespace(num_edges=m)],
-                       chunk_edges=chunk_edges, cfg=AdwiseConfig(k=k)).scan_steps
     log(f"{measured()}: scan_calls={st['scan_calls']} x {steps} steps, "
         f"{t_part / (st['scan_calls'] * steps) * 1e6:.1f} us per step; "
         f"h2d_bytes={st['h2d_bytes']} h2d_wait_s={st['h2d_wait_s']:.4f}")
@@ -237,11 +292,105 @@ def run_small_parity(run_dir: Path, *, k: int, seed: int,
         log("finding: adwise on this device vs the host CPU: not measured "
             "(no CPU backend in this process)")
         return
+    compare_adwise_backends(e, n, k, seed, mem, host)
+
+
+def adwise_step_trace(e, n: int, k: int, seed: int) -> dict:
+    """The in-memory ADWISE scan's decisions, one per step, on the default
+    device: the stream index and partition taken, and the best and
+    runner-up entries of that step's score matrix g. g is rebuilt from the
+    carries before and after the step with the scan's own terms (rescored
+    R+CS rows + λ·B over the live window slots); the step itself is the
+    scan's, unchanged."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core import AdwiseConfig, scoring
+    from repro.core.driver import AdwiseCore
+
+    cfg = AdwiseConfig(k=k, seed=seed)
+    core = AdwiseCore(cfg, n)
+    m = len(e)
+    allowed = jnp.ones((k,), bool)
+    cap = jnp.int32(core.cap_value(m, k))
+    step = core.make_step(jnp.asarray(e, jnp.int32), jnp.int32(m), allowed,
+                          cap, jnp.full((m,), -1, jnp.int32))
+
+    def traced(carry, _):
+        new, out = step(carry, None)
+        s = out.sidx[0]
+        # The taken slot left the window in this step; before it, it was live.
+        live = new.win_valid | ((new.win_sidx == s) & (s >= 0))
+        bal = scoring.balance_score(carry.sizes, allowed, cfg.eps)
+        ok_p = allowed & (carry.sizes < cap)
+        g = new.cached_rcs + carry.lam * bal[None, :]
+        g = jnp.where(live[:, None] & ok_p[None, :], g, scoring.NEG_INF)
+        return new, (s, out.p[0], jax.lax.top_k(g.reshape(-1), 2)[0])
+
+    scan = jax.jit(lambda c: jax.lax.scan(traced, c, None, length=m)[1])
+    sidx, p, top2 = (np.asarray(x) for x in scan(core.init_carry(0.0)))
+    assign = np.full(m, -1, np.int32)
+    assign[sidx[sidx >= 0]] = p[sidx >= 0]
+    return dict(sidx=sidx, p=p, top=top2[:, 0], second=top2[:, 1],
+                assign=assign)
+
+
+def compare_adwise_backends(e, n: int, k: int, seed: int, dev_assign,
+                            host) -> None:
+    """Finding, not a check: ADWISE on this device vs the host CPU on the
+    same stream -- agreement, quality of each, the first step where the two
+    decide differently with its top-two score gap on each backend, and
+    agreement again with float32 matmuls at the highest precision."""
+    import jax
+    import numpy as np
+
+    from repro.core import run_partitioner
+    from repro.graph import (
+        partition_balance,
+        replica_sets_from_assignment,
+        replication_degree,
+    )
+
+    plat = jax.devices()[0].platform
+    m = len(e)
     with jax.default_device(host):
-        on_host = run_partitioner("adwise", e, n, k, seed=seed).assign
-    log(f"finding: adwise on this device vs the host CPU backend: "
-        f"{int((on_host == mem).sum())} of {len(e)} assignments equal "
-        "(float32 scores may break ties differently)")
+        cpu_assign = run_partitioner("adwise", e, n, k, seed=seed).assign
+        cpu = adwise_step_trace(e, n, k, seed)
+    dev = adwise_step_trace(e, n, k, seed)
+    with jax.default_matmul_precision("highest"):
+        hi_assign = run_partitioner("adwise", e, n, k, seed=seed).assign
+
+    def quality(a) -> str:
+        rd = replication_degree(replica_sets_from_assignment(e, a, n, k))
+        return f"RD={rd:.6f} imbalance={partition_balance(a, k):.6f}"
+
+    log(f"finding: adwise on {plat} vs the host CPU: "
+        f"{int((dev_assign == cpu_assign).sum())} of {m} assignments equal; "
+        f"{plat} {quality(dev_assign)}, cpu {quality(cpu_assign)}")
+    log(f"finding: the step trace reproduces run_partitioner on {plat}: "
+        f"{bool((dev['assign'] == dev_assign).all())}, on cpu: "
+        f"{bool((cpu['assign'] == cpu_assign).all())}")
+    differ = (dev["sidx"] != cpu["sidx"]) | (dev["p"] != cpu["p"])
+    if differ.any():
+        t = int(np.argmax(differ))
+        drift = int((dev["top"][:t] != cpu["top"][:t]).sum())
+        gaps = ", ".join(
+            f"{name} takes edge {int(tr['sidx'][t])} -> partition "
+            f"{int(tr['p'][t])}, best {float(tr['top'][t]):.9g} runner-up "
+            f"{float(tr['second'][t]):.9g} (gap "
+            f"{float(tr['top'][t] - tr['second'][t]):.9g})"
+            for name, tr in ((plat, dev), ("cpu", cpu)))
+        log(f"finding: first differing decision at step {t} of {m}: {gaps}; "
+            f"the best score differed bitwise in {drift} of the {t} earlier "
+            "steps")
+    else:
+        log(f"finding: the step traces on {plat} and cpu take the same "
+            "decisions at every step")
+    log(f"finding: with jax_default_matmul_precision=highest on {plat}: "
+        f"{int((hi_assign == cpu_assign).sum())} of {m} assignments equal to "
+        f"the host CPU (default precision: "
+        f"{int((dev_assign == cpu_assign).sum())})")
 
 
 def run_four_chip(run_dir: Path, *, n_devices: int, k: int, seed: int,
@@ -296,8 +445,9 @@ def run_four_chip(run_dir: Path, *, n_devices: int, k: int, seed: int,
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--edges", type=int, default=1 << 18,
-                    help="stream only the first N edges of the file")
+    ap.add_argument("--edges", type=int, default=None,
+                    help="stream only the first N edges of the file "
+                         f"(default: {SCAN_CALLS} full ring scan calls)")
     ap.add_argument("--run-dir", default=str(ROOT / "runs" / "chip_smoke"))
     ap.add_argument("--four-chip", action="store_true",
                     help="run only the 4-device shard_map and engine-mesh "
@@ -325,6 +475,7 @@ def main(argv=None) -> int:
     cache = Path(compat.enable_compile_cache())
     n_cached = len(list(cache.glob("*"))) if cache.is_dir() else 0
     log(f"compile cache: {cache} ({n_cached} entries at start)")
+    clog = CompileLog()
     run_dir = Path(args.run_dir)
     run_dir.mkdir(parents=True, exist_ok=True)
     # A kernel-tier table from elsewhere must not steer what runs here.
@@ -334,14 +485,17 @@ def main(argv=None) -> int:
         run_four_chip(run_dir, n_devices=want, k=K, seed=SEED, scale=18,
                       edges=1 << 16, chunk_edges=1 << 14)
     else:
-        run_main_path(run_dir, scale=SCALE, edges=args.edges, k=K,
-                      chunk_edges=CHUNK_EDGES, iters=ITERS, seed=SEED)
+        edges = args.edges or SCAN_CALLS * scan_steps(K, CHUNK_EDGES)
+        run_main_path(run_dir, scale=SCALE, edges=edges, k=K,
+                      chunk_edges=CHUNK_EDGES, iters=ITERS, seed=SEED,
+                      clog=clog)
         run_small_parity(run_dir, k=K, seed=SEED)
         stats = dev.memory_stats() or {}
         log(f"{measured()}: peak_bytes_in_use="
             f"{stats.get('peak_bytes_in_use', 'not reported')}")
     n_cached = len(list(cache.glob("*"))) if cache.is_dir() else 0
-    log(f"compile cache: {n_cached} entries at end")
+    log(f"compile cache: {n_cached} entries at end; whole run: "
+        f"{clog.since(dict.fromkeys(clog.counts, 0))}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(devices)}}), flush=True)

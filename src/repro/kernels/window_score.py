@@ -12,11 +12,13 @@ neighbourhood test is an O(W²) endpoint-match which we phrase as two
 
   grid  = (W / BW,)                       one program per row tile
   VMEM  = u,v,valid (1, W) rows and replica tables (W, K) for the match;
-          the tile's u,v,valid,2-Ψ (BW, 1) columns and replica rows
-          (BW, K); λ·B/allowed (1, K); out tile (BW, K)
+          the tile's u,v,valid,2-Ψ (BW, 1) columns; λ·B/allowed (1, K);
+          out tile (BW, K)
 
-Each program's row tile arrives through its own BlockSpec, so the kernel
-never slices a loaded value (Mosaic has no value-level dynamic_slice).
+The tile's replica rows are a ref-level ``pl.ds`` load from the whole-window
+tables; the (BW, 1) columns come through their own BlockSpecs so the kernel
+needs no transpose. Nothing slices a loaded value (Mosaic has no
+value-level dynamic_slice).
 
 W and K are padded to multiples of (BW=128, 128) so matmul operands are
 MXU-aligned. Padded rows/columns carry valid=0 / allowed=0 and are masked to
@@ -45,8 +47,6 @@ def _kernel(
     valid_ref,  # (BW, 1) int32 (0/1)
     fu_ref,  # (BW, 1) f32    2 - Ψ_u
     fv_ref,  # (BW, 1) f32    2 - Ψ_v
-    repu_ref,  # (BW, K) f32   replica rows of this tile's u
-    repv_ref,  # (BW, K) f32
     repu_all_ref,  # (W, K) f32  replica rows of every window edge's u
     repv_all_ref,  # (W, K) f32
     lbal_ref,  # (1, K) f32    λ·B(p)
@@ -55,8 +55,9 @@ def _kernel(
     *,
     use_cs: bool,
 ):
-    # Degree-aware replication score R (Eq. 5).
-    g = repu_ref[...] * fu_ref[...] + repv_ref[...] * fv_ref[...]
+    # Degree-aware replication score R (Eq. 5) on this tile's rows.
+    rows = pl.ds(pl.multiple_of(pl.program_id(0) * BW, BW), BW)
+    g = repu_all_ref[rows, :] * fu_ref[...] + repv_all_ref[rows, :] * fv_ref[...]
 
     if use_cs:
         # Window-local neighbourhood match (CS, Eq. 6) as MXU matmuls.
@@ -140,8 +141,6 @@ def window_score_pallas(
             pl.BlockSpec((BW, 1), tile),  # valid tile
             pl.BlockSpec((BW, 1), tile),  # 2 - Ψ_u tile
             pl.BlockSpec((BW, 1), tile),  # 2 - Ψ_v tile
-            pl.BlockSpec((BW, k_pad), tile),  # rep_u tile
-            pl.BlockSpec((BW, k_pad), tile),  # rep_v tile
             pl.BlockSpec((w_pad, k_pad), full),  # rep_u, whole window
             pl.BlockSpec((w_pad, k_pad), full),  # rep_v, whole window
             pl.BlockSpec((1, k_pad), full),  # λ·B
@@ -150,5 +149,5 @@ def window_score_pallas(
         out_specs=pl.BlockSpec((BW, k_pad), tile),
         out_shape=jax.ShapeDtypeStruct((w_pad, k_pad), jnp.float32),
         interpret=interpret,
-    )(u.T, v.T, valid.T, u, v, valid, fu, fv, ru, rv, ru, rv, lbal, al)
+    )(u.T, v.T, valid.T, u, v, valid, fu, fv, ru, rv, lbal, al)
     return out[:w, :k]

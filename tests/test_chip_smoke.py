@@ -20,6 +20,7 @@ def test_main_path_checks_pass_at_tiny_size(tmp_path, capsys):
                                    chunk_edges=512, iters=3, seed=0)
     out = capsys.readouterr().out
     assert "unassigned=0" in out and "max rel err" in out
+    assert "setup: XLA compile or cache load" in out
     assert got["rd"] >= 1.0 and got["superstep_s"] > 0
     # Timed lines name the platform they were taken on, never "chip" here.
     assert "measured on cpu" in out and "measured on chip" not in out
@@ -30,6 +31,35 @@ def test_small_graph_parity_holds_at_tiny_size(tmp_path, capsys):
     out = capsys.readouterr().out
     assert "bit-identical to their numpy oracles" in out
     assert "file path bit-identical" in out
+
+
+def test_whole_scan_calls_edge_count_runs_that_many_calls(tmp_path, capsys):
+    """The default stream length is a whole number of ring scan calls, so
+    no call runs its full step count for a few leftover edges."""
+    steps = chip_smoke.scan_steps(4, 512)
+    chip_smoke.run_main_path(tmp_path, scale=10, edges=3 * steps, k=4,
+                             chunk_edges=512, iters=2, seed=0)
+    assert f"scan_calls=3 x {steps} steps" in capsys.readouterr().out
+    assert chip_smoke.SCAN_CALLS * chip_smoke.scan_steps(
+        chip_smoke.K, chip_smoke.CHUNK_EDGES) == 4 * 65280
+
+
+def test_adwise_step_trace_reproduces_the_scan(capsys):
+    """The backend comparison's step trace takes the scan's own decisions,
+    and its rebuilt best score is the best entry at every step."""
+    from repro.core import run_partitioner
+    from repro.graph import make_graph
+
+    e, n = make_graph("brain_like", seed=0, scale=0.01)
+    mem = run_partitioner("adwise", e, n, 4, seed=0).assign
+    tr = chip_smoke.adwise_step_trace(e, n, 4, 0)
+    np.testing.assert_array_equal(tr["assign"], mem)
+    assert (tr["top"] >= tr["second"]).all() and (tr["top"] > -1e29).all()
+    chip_smoke.compare_adwise_backends(e, n, 4, 0, mem, jax.devices("cpu")[0])
+    out = capsys.readouterr().out
+    assert f"{len(e)} of {len(e)} assignments equal" in out
+    assert "reproduces run_partitioner on cpu: True, on cpu: True" in out
+    assert "same decisions at every step" in out
 
 
 def test_pagerank_reference_matches_engine(tiny_graph):

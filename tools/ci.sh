@@ -82,9 +82,10 @@ python -m tools.bench_compare bench_logs \
   || echo "WARN: bench_compare flagged a wall regression (warn-only here)"
 
 # Multi-device path: batched spotlight (shard_map over instances) + padded
-# engine mesh on 2 fake CPU devices, every run.
+# engine mesh on 2 fake CPU devices, every run (bench_scaling measures in
+# this process, over N in {1,2} of its devices).
 XLA_FLAGS="--xla_force_host_platform_device_count=2" \
-  python -m benchmarks.bench_scaling --smoke --in-process
+  python -m benchmarks.bench_scaling --smoke
 
 # Ring-buffer smoke: text ingest (bytes vs python parser parity) -> binary
 # -> file-driven partitioning in a tmpdir. Asserted inside: bit-parity with
