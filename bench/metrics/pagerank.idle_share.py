@@ -1,0 +1,8 @@
+"""Share of the PageRank window in which no operation ran on the chip."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    if t["busy_s"] <= 0 or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
