@@ -172,214 +172,221 @@ def _make_step(
     m_pad = stream.shape[0]
     slot_ids = jnp.arange(w_max, dtype=jnp.int32)
 
+    # The phases carry named scopes (adwise.window: 1; adwise.score: 2-4;
+    # adwise.pick: 5; adwise.apply: 6-7), which reach the compiled program
+    # as op metadata only: the profiler attributes device time by them.
     def step(carry: Carry, _) -> tuple[Carry, StepOut]:
-        # ---- 1) Refill invalid slots up to the logical window size w. ----
-        need = jnp.clip(carry.w_cap - carry.n_valid, 0, w_max)
-        avail = jnp.maximum(m_real - carry.cursor, 0)
-        take = jnp.minimum(need, avail)
-        inv = ~carry.win_valid
-        rank = jnp.cumsum(inv.astype(jnp.int32)) - 1
-        fill = inv & (rank < take)
-        src = carry.cursor + rank
-        # Ring addressing: logical row s lives at slot s % m_pad. For a
-        # resident stream m_pad == m, so this is the identity on every live
-        # index; reads past the live range are masked by `fill`.
-        src_c = src % m_pad
-        fill_uv = stream[src_c]
-        win_uv = jnp.where(fill[:, None], fill_uv, carry.win_uv)
-        win_sidx = jnp.where(fill, src, carry.win_sidx)
-        win_valid = carry.win_valid | fill
-        # Streamed degrees update on observation (first pass only — warm
-        # passes inherit the final degree table and must not re-count).
-        if update_deg:
-            u_f = jnp.where(fill, fill_uv[:, 0], v_dummy)
-            v_f = jnp.where(fill, fill_uv[:, 1], v_dummy)
-            deg = carry.deg.at[u_f].add(1).at[v_f].add(1)
-            seen = jnp.where(fill, jnp.maximum(deg[u_f], deg[v_f]), 0)
-            max_deg = jnp.maximum(carry.max_deg, jnp.max(seen))
-        else:
-            deg = carry.deg
-            max_deg = carry.max_deg
-        # Buffered re-streaming revocation: the prior pass's assignment of an
-        # edge is released when the edge enters the window, so balance/capacity
-        # terms score against net loads while the pass re-places the stream.
-        pa = prev_assign[src_c]
-        dec = fill & (pa >= 0)
-        sizes_net = carry.sizes.at[jnp.where(dec, pa, 0)].add(
-            -dec.astype(jnp.int32)
-        )
-        cursor = carry.cursor + take
-        n_valid = carry.n_valid + take
-
-        u = win_uv[:, 0]
-        v = win_uv[:, 1]
-
-        # ---- 2) Lazy traversal: pick ≤ r_sel stale slots to rescore. ----
-        ver_u = carry.rep_version[u]
-        ver_v = carry.rep_version[v]
-        if cfg.lazy:
-            # A refilled slot's cache belongs to the previous occupant — always stale.
-            stale = win_valid & (
-                (ver_u != carry.cached_ver_u) | (ver_v != carry.cached_ver_v) | fill
+        with jax.named_scope("adwise.window"):
+            # ---- 1) Refill invalid slots up to the logical window size w. ----
+            need = jnp.clip(carry.w_cap - carry.n_valid, 0, w_max)
+            avail = jnp.maximum(m_real - carry.cursor, 0)
+            take = jnp.minimum(need, avail)
+            inv = ~carry.win_valid
+            rank = jnp.cumsum(inv.astype(jnp.int32)) - 1
+            fill = inv & (rank < take)
+            src = carry.cursor + rank
+            # Ring addressing: logical row s lives at slot s % m_pad. For a
+            # resident stream m_pad == m, so this is the identity on every live
+            # index; reads past the live range are masked by `fill`.
+            src_c = src % m_pad
+            fill_uv = stream[src_c]
+            win_uv = jnp.where(fill[:, None], fill_uv, carry.win_uv)
+            win_sidx = jnp.where(fill, src, carry.win_sidx)
+            win_valid = carry.win_valid | fill
+            # Streamed degrees update on observation (first pass only — warm
+            # passes inherit the final degree table and must not re-count).
+            if update_deg:
+                u_f = jnp.where(fill, fill_uv[:, 0], v_dummy)
+                v_f = jnp.where(fill, fill_uv[:, 1], v_dummy)
+                deg = carry.deg.at[u_f].add(1).at[v_f].add(1)
+                seen = jnp.where(fill, jnp.maximum(deg[u_f], deg[v_f]), 0)
+                max_deg = jnp.maximum(carry.max_deg, jnp.max(seen))
+            else:
+                deg = carry.deg
+                max_deg = carry.max_deg
+            # Buffered re-streaming revocation: the prior pass's assignment of an
+            # edge is released when the edge enters the window, so balance/capacity
+            # terms score against net loads while the pass re-places the stream.
+            pa = prev_assign[src_c]
+            dec = fill & (pa >= 0)
+            sizes_net = carry.sizes.at[jnp.where(dec, pa, 0)].add(
+                -dec.astype(jnp.int32)
             )
-        else:
-            # Faithful mode: every valid window edge is rescored every step
-            # (CS depends on *other* window edges, which version stamps on the
-            # own endpoints cannot see).
-            stale = win_valid
-        # Priority classes: fresh window entries first, then stale candidates
-        # (cached score above Θ), then stale secondary edges (§III-B).
-        cand = carry.cached_rcs.max(axis=1) >= carry.theta
-        cls = jnp.where(fill, 0, jnp.where(cand, 1, 2)).astype(jnp.int32)
-        key = jnp.where(stale, cls * w_max + slot_ids, _BIG_I32)
-        order = jnp.argsort(key)[:r_sel]
-        sel_live = jnp.sort(key)[:r_sel] < _BIG_I32
-        sel_idx = jnp.where(sel_live, order, w_max)  # dummy slot w_max
-        sel_c = jnp.clip(sel_idx, 0, w_max - 1)
+            cursor = carry.cursor + take
+            n_valid = carry.n_valid + take
 
-        # ---- 3) Fresh R (+ CS) for the selected rows. ----
-        rep_u = carry.replicas[u]  # (W, K)
-        rep_v = carry.replicas[v]
-        r_all = scoring.replication_score(rep_u, rep_v, deg[u], deg[v], max_deg)
-        rcs_rows = r_all[sel_c]
-        if cfg.use_clustering:
-            u_s, v_s = u[sel_c], v[sel_c]
-            keep = win_valid[None, :] & (sel_c[:, None] != slot_ids[None, :])
-            a = ((u[None, :] == u_s[:, None]) | (u[None, :] == v_s[:, None])) & keep
-            bm = ((v[None, :] == u_s[:, None]) | (v[None, :] == v_s[:, None])) & keep
-            af = a.astype(jnp.float32)
-            bf = bm.astype(jnp.float32)
-            num = af @ rep_v.astype(jnp.float32) + bf @ rep_u.astype(jnp.float32)
-            den = af.sum(axis=1) + bf.sum(axis=1)
-            rcs_rows = rcs_rows + num / jnp.maximum(den, 1.0)[:, None]
-        cached_rcs = (
-            jnp.zeros((w_max + 1, k), jnp.float32)
-            .at[:w_max]
-            .set(carry.cached_rcs)
-            .at[sel_idx]
-            .set(rcs_rows)[:w_max]
-        )
-        pad1 = lambda x, fillv: jnp.concatenate([x, jnp.full((1,), fillv, x.dtype)])
-        cached_ver_u = pad1(carry.cached_ver_u, -1).at[sel_idx].set(ver_u[sel_c])[:w_max]
-        cached_ver_v = pad1(carry.cached_ver_v, -1).at[sel_idx].set(ver_v[sel_c])[:w_max]
-        n_scored = jnp.sum(sel_live.astype(jnp.int32))
-        score_rows = carry.score_rows + n_scored
+            u = win_uv[:, 0]
+            v = win_uv[:, 1]
 
-        # ---- 4) Score matrix g = cached RCS + λ·B, masked. ----
-        bal = scoring.balance_score(sizes_net, allowed, cfg.eps)
-        ok_p = allowed & (sizes_net < cap)
-        g = cached_rcs + carry.lam * bal[None, :]
-        g = jnp.where(win_valid[:, None] & ok_p[None, :], g, NEG_INF)
-        # Candidate threshold Θ = g_avg + ε (§III-B) in RCS units — it gates
-        # the cached R+CS values, so exclude the λ·B term common to a column.
-        rcs_max = cached_rcs.max(axis=1)
-        nv = jnp.maximum(jnp.sum(win_valid.astype(jnp.float32)), 1.0)
-        theta = jnp.sum(jnp.where(win_valid, rcs_max, 0.0)) / nv + cfg.eps
+        with jax.named_scope("adwise.score"):
+            # ---- 2) Lazy traversal: pick ≤ r_sel stale slots to rescore. ----
+            ver_u = carry.rep_version[u]
+            ver_v = carry.rep_version[v]
+            if cfg.lazy:
+                # A refilled slot's cache belongs to the previous occupant — always stale.
+                stale = win_valid & (
+                    (ver_u != carry.cached_ver_u) | (ver_v != carry.cached_ver_v) | fill
+                )
+            else:
+                # Faithful mode: every valid window edge is rescored every step
+                # (CS depends on *other* window edges, which version stamps on the
+                # own endpoints cannot see).
+                stale = win_valid
+            # Priority classes: fresh window entries first, then stale candidates
+            # (cached score above Θ), then stale secondary edges (§III-B).
+            cand = carry.cached_rcs.max(axis=1) >= carry.theta
+            cls = jnp.where(fill, 0, jnp.where(cand, 1, 2)).astype(jnp.int32)
+            key = jnp.where(stale, cls * w_max + slot_ids, _BIG_I32)
+            order = jnp.argsort(key)[:r_sel]
+            sel_live = jnp.sort(key)[:r_sel] < _BIG_I32
+            sel_idx = jnp.where(sel_live, order, w_max)  # dummy slot w_max
+            sel_c = jnp.clip(sel_idx, 0, w_max - 1)
 
-        # ---- 5) Assign the top-b vertex-disjoint window edges. ----
-        def pick(i, st):
-            g_m, ch_mask, ch_p, out_s, out_p, sum_gacc = st
-            flat = jnp.argmax(g_m)
-            slot = (flat // k).astype(jnp.int32)
-            p = (flat % k).astype(jnp.int32)
-            ok = g_m[slot, p] > NEG_INF / 2
-            out_s = out_s.at[i].set(jnp.where(ok, win_sidx[slot], -1))
-            out_p = out_p.at[i].set(jnp.where(ok, p, 0))
-            share = (u == u[slot]) | (u == v[slot]) | (v == u[slot]) | (v == v[slot])
-            g_m = jnp.where((share & ok)[:, None], NEG_INF, g_m)
-            ch_mask = ch_mask.at[slot].max(ok)
-            ch_p = ch_p.at[slot].set(jnp.where(ok, p, ch_p[slot]))
-            sum_gacc = sum_gacc + jnp.where(ok, g[slot, p], 0.0)
-            return (g_m, ch_mask, ch_p, out_s, out_p, sum_gacc)
+            # ---- 3) Fresh R (+ CS) for the selected rows. ----
+            rep_u = carry.replicas[u]  # (W, K)
+            rep_v = carry.replicas[v]
+            r_all = scoring.replication_score(rep_u, rep_v, deg[u], deg[v], max_deg)
+            rcs_rows = r_all[sel_c]
+            if cfg.use_clustering:
+                u_s, v_s = u[sel_c], v[sel_c]
+                keep = win_valid[None, :] & (sel_c[:, None] != slot_ids[None, :])
+                a = ((u[None, :] == u_s[:, None]) | (u[None, :] == v_s[:, None])) & keep
+                bm = ((v[None, :] == u_s[:, None]) | (v[None, :] == v_s[:, None])) & keep
+                af = a.astype(jnp.float32)
+                bf = bm.astype(jnp.float32)
+                num = af @ rep_v.astype(jnp.float32) + bf @ rep_u.astype(jnp.float32)
+                den = af.sum(axis=1) + bf.sum(axis=1)
+                rcs_rows = rcs_rows + num / jnp.maximum(den, 1.0)[:, None]
+            cached_rcs = (
+                jnp.zeros((w_max + 1, k), jnp.float32)
+                .at[:w_max]
+                .set(carry.cached_rcs)
+                .at[sel_idx]
+                .set(rcs_rows)[:w_max]
+            )
+            pad1 = lambda x, fillv: jnp.concatenate([x, jnp.full((1,), fillv, x.dtype)])
+            cached_ver_u = pad1(carry.cached_ver_u, -1).at[sel_idx].set(ver_u[sel_c])[:w_max]
+            cached_ver_v = pad1(carry.cached_ver_v, -1).at[sel_idx].set(ver_v[sel_c])[:w_max]
+            n_scored = jnp.sum(sel_live.astype(jnp.int32))
+            score_rows = carry.score_rows + n_scored
 
-        st0 = (
-            g,
-            jnp.zeros((w_max,), bool),
-            jnp.zeros((w_max,), jnp.int32),
-            jnp.full((b,), -1, jnp.int32),
-            jnp.zeros((b,), jnp.int32),
-            jnp.zeros((), jnp.float32),
-        )
-        if b == 1:
-            st = pick(0, st0)
-        else:
-            st = jax.lax.fori_loop(0, b, pick, st0)
-        _, ch, ch_p, out_s, out_p, g_sum = st
-        n_ch = jnp.sum(ch.astype(jnp.int32))
+            # ---- 4) Score matrix g = cached RCS + λ·B, masked. ----
+            bal = scoring.balance_score(sizes_net, allowed, cfg.eps)
+            ok_p = allowed & (sizes_net < cap)
+            g = cached_rcs + carry.lam * bal[None, :]
+            g = jnp.where(win_valid[:, None] & ok_p[None, :], g, NEG_INF)
+            # Candidate threshold Θ = g_avg + ε (§III-B) in RCS units — it gates
+            # the cached R+CS values, so exclude the λ·B term common to a column.
+            rcs_max = cached_rcs.max(axis=1)
+            nv = jnp.maximum(jnp.sum(win_valid.astype(jnp.float32)), 1.0)
+            theta = jnp.sum(jnp.where(win_valid, rcs_max, 0.0)) / nv + cfg.eps
 
-        # ---- 6) Apply assignments to the vertex cache / partition state. ----
-        chi = ch.astype(jnp.int32)
-        sizes = sizes_net.at[ch_p].add(chi)  # adds 0 where not chosen
-        u_c = jnp.where(ch, u, v_dummy)
-        v_c = jnp.where(ch, v, v_dummy)
-        old_u = carry.replicas[u_c, ch_p]
-        old_v = carry.replicas[v_c, ch_p]
-        replicas = carry.replicas.at[u_c, ch_p].max(ch).at[v_c, ch_p].max(ch)
-        new_u = (ch & ~old_u).astype(jnp.int32)
-        new_v = (ch & ~old_v).astype(jnp.int32)
-        rep_version = carry.rep_version.at[u_c].add(new_u).at[v_c].add(new_v)
-        win_valid = win_valid & ~ch
-        n_valid = n_valid - n_ch
-        assigned = carry.assigned + n_ch
+        with jax.named_scope("adwise.pick"):
+            # ---- 5) Assign the top-b vertex-disjoint window edges. ----
+            def pick(i, st):
+                g_m, ch_mask, ch_p, out_s, out_p, sum_gacc = st
+                flat = jnp.argmax(g_m)
+                slot = (flat // k).astype(jnp.int32)
+                p = (flat % k).astype(jnp.int32)
+                ok = g_m[slot, p] > NEG_INF / 2
+                out_s = out_s.at[i].set(jnp.where(ok, win_sidx[slot], -1))
+                out_p = out_p.at[i].set(jnp.where(ok, p, 0))
+                share = (u == u[slot]) | (u == v[slot]) | (v == u[slot]) | (v == v[slot])
+                g_m = jnp.where((share & ok)[:, None], NEG_INF, g_m)
+                ch_mask = ch_mask.at[slot].max(ok)
+                ch_p = ch_p.at[slot].set(jnp.where(ok, p, ch_p[slot]))
+                sum_gacc = sum_gacc + jnp.where(ok, g[slot, p], 0.0)
+                return (g_m, ch_mask, ch_p, out_s, out_p, sum_gacc)
 
-        lam = scoring.lambda_update(
-            carry.lam, sizes, allowed, assigned, m_real, cfg.lam_lo, cfg.lam_hi
-        )
+            st0 = (
+                g,
+                jnp.zeros((w_max,), bool),
+                jnp.zeros((w_max,), jnp.int32),
+                jnp.full((b,), -1, jnp.int32),
+                jnp.zeros((b,), jnp.int32),
+                jnp.zeros((), jnp.float32),
+            )
+            if b == 1:
+                st = pick(0, st0)
+            else:
+                st = jax.lax.fori_loop(0, b, pick, st0)
+            _, ch, ch_p, out_s, out_p, g_sum = st
+            n_ch = jnp.sum(ch.astype(jnp.int32))
 
-        # ---- 7) Modeled latency + adaptive window controller (§III-A). ----
-        step_cost = n_scored.astype(jnp.float32) * jnp.float32(k) * carry.cost_per_score + carry.base_cost
-        budget_left = carry.budget_left - step_cost
-        lat_edge = step_cost / jnp.maximum(n_ch.astype(jnp.float32), 1.0)
-        lat_ema = jnp.where(
-            carry.assigned == 0, lat_edge, 0.9 * carry.lat_ema + 0.1 * lat_edge
-        )
-        c = carry.c + n_ch
-        sum_g = carry.sum_g + g_sum
-        trigger = jnp.asarray(cfg.adapt) & (c >= carry.w_cap)
-        avg_g = sum_g / jnp.maximum(c.astype(jnp.float32), 1.0)
-        c1 = (~carry.last_grew) | (avg_g >= carry.avg_g_prev)
-        if has_budget:
-            edges_left = jnp.maximum(m_real - assigned, 1).astype(jnp.float32)
-            c2 = lat_ema < budget_left / edges_left
-        else:
-            c2 = jnp.asarray(True)
-        grow = trigger & c1 & c2 & (carry.w_cap < w_max)
-        shrink = trigger & ~c2
-        w_lo = jnp.int32(max(1, b))
-        w_new = jnp.where(
-            grow,
-            jnp.minimum(2 * carry.w_cap, w_max),
-            jnp.where(shrink, jnp.maximum((carry.w_cap + 1) // 2, w_lo), carry.w_cap),
-        )
-        out = StepOut(sidx=out_s, p=out_p, w_cap=carry.w_cap, g_chosen=g_sum)
-        new_carry = Carry(
-            replicas=replicas,
-            rep_version=rep_version,
-            deg=deg,
-            max_deg=max_deg,
-            sizes=sizes,
-            lam=lam,
-            w_cap=w_new,
-            cursor=cursor,
-            n_valid=n_valid,
-            win_uv=win_uv,
-            win_sidx=win_sidx,
-            win_valid=win_valid,
-            cached_rcs=cached_rcs,
-            cached_ver_u=cached_ver_u,
-            cached_ver_v=cached_ver_v,
-            theta=theta,
-            assigned=assigned,
-            score_rows=score_rows,
-            c=jnp.where(trigger, 0, c),
-            sum_g=jnp.where(trigger, 0.0, sum_g),
-            avg_g_prev=jnp.where(trigger, avg_g, carry.avg_g_prev),
-            last_grew=jnp.where(trigger, grow, carry.last_grew),
-            budget_left=budget_left,
-            lat_ema=lat_ema,
-            cost_per_score=carry.cost_per_score,
-            base_cost=carry.base_cost,
-        )
+        with jax.named_scope("adwise.apply"):
+            # ---- 6) Apply assignments to the vertex cache / partition state. ----
+            chi = ch.astype(jnp.int32)
+            sizes = sizes_net.at[ch_p].add(chi)  # adds 0 where not chosen
+            u_c = jnp.where(ch, u, v_dummy)
+            v_c = jnp.where(ch, v, v_dummy)
+            old_u = carry.replicas[u_c, ch_p]
+            old_v = carry.replicas[v_c, ch_p]
+            replicas = carry.replicas.at[u_c, ch_p].max(ch).at[v_c, ch_p].max(ch)
+            new_u = (ch & ~old_u).astype(jnp.int32)
+            new_v = (ch & ~old_v).astype(jnp.int32)
+            rep_version = carry.rep_version.at[u_c].add(new_u).at[v_c].add(new_v)
+            win_valid = win_valid & ~ch
+            n_valid = n_valid - n_ch
+            assigned = carry.assigned + n_ch
+
+            lam = scoring.lambda_update(
+                carry.lam, sizes, allowed, assigned, m_real, cfg.lam_lo, cfg.lam_hi
+            )
+
+            # ---- 7) Modeled latency + adaptive window controller (§III-A). ----
+            step_cost = n_scored.astype(jnp.float32) * jnp.float32(k) * carry.cost_per_score + carry.base_cost
+            budget_left = carry.budget_left - step_cost
+            lat_edge = step_cost / jnp.maximum(n_ch.astype(jnp.float32), 1.0)
+            lat_ema = jnp.where(
+                carry.assigned == 0, lat_edge, 0.9 * carry.lat_ema + 0.1 * lat_edge
+            )
+            c = carry.c + n_ch
+            sum_g = carry.sum_g + g_sum
+            trigger = jnp.asarray(cfg.adapt) & (c >= carry.w_cap)
+            avg_g = sum_g / jnp.maximum(c.astype(jnp.float32), 1.0)
+            c1 = (~carry.last_grew) | (avg_g >= carry.avg_g_prev)
+            if has_budget:
+                edges_left = jnp.maximum(m_real - assigned, 1).astype(jnp.float32)
+                c2 = lat_ema < budget_left / edges_left
+            else:
+                c2 = jnp.asarray(True)
+            grow = trigger & c1 & c2 & (carry.w_cap < w_max)
+            shrink = trigger & ~c2
+            w_lo = jnp.int32(max(1, b))
+            w_new = jnp.where(
+                grow,
+                jnp.minimum(2 * carry.w_cap, w_max),
+                jnp.where(shrink, jnp.maximum((carry.w_cap + 1) // 2, w_lo), carry.w_cap),
+            )
+            out = StepOut(sidx=out_s, p=out_p, w_cap=carry.w_cap, g_chosen=g_sum)
+            new_carry = Carry(
+                replicas=replicas,
+                rep_version=rep_version,
+                deg=deg,
+                max_deg=max_deg,
+                sizes=sizes,
+                lam=lam,
+                w_cap=w_new,
+                cursor=cursor,
+                n_valid=n_valid,
+                win_uv=win_uv,
+                win_sidx=win_sidx,
+                win_valid=win_valid,
+                cached_rcs=cached_rcs,
+                cached_ver_u=cached_ver_u,
+                cached_ver_v=cached_ver_v,
+                theta=theta,
+                assigned=assigned,
+                score_rows=score_rows,
+                c=jnp.where(trigger, 0, c),
+                sum_g=jnp.where(trigger, 0.0, sum_g),
+                avg_g_prev=jnp.where(trigger, avg_g, carry.avg_g_prev),
+                last_grew=jnp.where(trigger, grow, carry.last_grew),
+                budget_left=budget_left,
+                lat_ema=lat_ema,
+                cost_per_score=carry.cost_per_score,
+                base_cost=carry.base_cost,
+            )
         return new_carry, out
 
     return step

@@ -108,6 +108,13 @@ Callers surface the counters in partition stats, and
 :data:`~repro.engine.latency_model.H2D_BW_BPS` when only modeled traffic
 is available, overlap-aware (``max(io, h2d, compute)``) when a prefetch
 depth and measured stalls are present.
+
+Host-serial accounting (:class:`HostSerial`): the ring loop reports the
+host time with no scan call in flight (``host_serial_s``: from each sync's
+return to the next dispatch) and the device→host reads it makes
+(``host_syncs``: four per call — ``assigned``, ``cursor``, ``sidx``,
+``p``). Its spans (``refill``, ``dispatch``, ``refill-spec``, ``sync``,
+``emit`` under ``scan-call``) take their timestamps from the same floats.
 """
 from __future__ import annotations
 
@@ -139,6 +146,7 @@ __all__ = [
     "StreamResidency",
     "ScanDriver",
     "DriveResult",
+    "HostSerial",
     "resolve_backend",
     "resolve_prefetch",
     "scan_compile_counts",
@@ -619,6 +627,9 @@ class _ReadAhead:
                 # the worker is on disk.
                 trace = src.trace
                 t_stage = time.perf_counter()
+                # Opened from the worker thread, so the span lands on the
+                # `adwise-readahead` track.
+                stage = trace.span("stage", "stage").open(t_stage)
                 uv: Optional[np.ndarray] = None
                 if not src.uv_resident[i]:
                     uv = np.ascontiguousarray(
@@ -639,13 +650,9 @@ class _ReadAhead:
                     )
                 t_staged = time.perf_counter()
                 if trace.enabled:
-                    # Recorded from the worker thread, so the span lands on
-                    # the `adwise-readahead` track.
-                    trace.add_span(
-                        "stage", "stage", t_stage, t_staged,
-                        attrs=dict(instance=i, start=start, rows=c,
-                                   prev=prev is not None),
-                    )
+                    stage.set(instance=i, start=start, rows=c,
+                              prev=prev is not None)
+                stage.close(t_staged)
                 with self._cv:
                     # Worker-side staging wall: the blind spot h2d_wait_s
                     # (blocking refills only) cannot see. Accumulated even
@@ -929,7 +936,9 @@ class FileSource:
         self.h2d_calls += 1
         trace = self.trace
         traced = trace.enabled
-        t_start = time.perf_counter() if (traced or not speculative) else 0.0
+        name = "refill-spec" if speculative else "refill"
+        t_start = time.perf_counter()
+        span = trace.span(name, name).open(t_start)
         shipped_rows = 0
         call_spans = 0
         call_missed = 0
@@ -959,15 +968,11 @@ class FileSource:
                 slot = hi % self.B
                 # Never wrap inside a write; never exceed the chunk bound.
                 c = min(end - hi, self.B - slot, self.max_span)
-                if traced:
-                    t_fetch = time.perf_counter()
-                rows, prows, waited = self._fetch(i, hi, c)
-                if traced:
-                    trace.add_span(
-                        "fetch", "fetch", t_fetch, time.perf_counter(),
-                        attrs=dict(instance=i, start=hi, rows=c,
-                                   prestaged=not waited),
-                    )
+                with trace.span("fetch", "fetch") as fetch:
+                    rows, prows, waited = self._fetch(i, hi, c)
+                    if traced:
+                        fetch.set(instance=i, start=hi, rows=c,
+                                  prestaged=not waited)
                 self.refill_spans += 1
                 call_spans += 1
                 if waited:
@@ -992,23 +997,16 @@ class FileSource:
                 shipped_rows += c
                 hi += c
             self.hi[i] = hi
+        if traced:
+            span.set(rows=shipped_rows, spans=call_spans, missed=call_missed,
+                     Rq=self.Rq)
+        t_end = time.perf_counter()
+        # Same (t_start, t_end) floats that feed h2d_wait_s: the `refill`
+        # category total reconciles with it exactly. A speculative refill
+        # overlaps the in-flight scan and is not a stall.
+        span.close(t_end)
         if not speculative:
-            t_end = time.perf_counter()
             self.h2d_wait_s += t_end - t_start
-            if traced:
-                # Same (t_start, t_end) floats that fed h2d_wait_s: the
-                # `refill` category total reconciles with it exactly.
-                trace.add_span(
-                    "refill", "refill", t_start, t_end,
-                    attrs=dict(rows=shipped_rows, spans=call_spans,
-                               missed=call_missed, Rq=self.Rq),
-                )
-        elif traced and call_spans:
-            trace.add_span(
-                "refill-spec", "refill-spec", t_start, time.perf_counter(),
-                attrs=dict(rows=shipped_rows, spans=call_spans,
-                           missed=call_missed, Rq=self.Rq),
-            )
         return buf
 
     def close(self) -> None:
@@ -1064,6 +1062,44 @@ class DriveResult(NamedTuple):
     # Worker-side staging wall (read-ahead thread): the time spent reading
     # and preparing spans the blocking h2d_wait_s stall cannot see.
     prestage_wall_s: float = 0.0
+
+
+class HostSerial:
+    """Host time with no scan call in flight, and the device→host reads
+    of the stepping loop, over one caller's run (one ``partition_file``
+    call, over all its ring passes).
+
+    Stretches run from ``t_entry`` to the first scan dispatch — the
+    ``init`` span of ``trace``, opened here when a tracer is given — from
+    each sync's return to the next dispatch, and from the last sync's
+    return to :meth:`finish`. The driver passes the same floats to its
+    spans.
+    """
+
+    __slots__ = ("serial_s", "syncs", "_free", "_init")
+
+    def __init__(self, trace: Any = None, t_entry: Optional[float] = None) -> None:
+        t = time.perf_counter() if t_entry is None else t_entry
+        self.serial_s = 0.0
+        self.syncs = 0
+        self._free = t
+        self._init: Any = (
+            None if trace is None else trace.span("init", "phase").open(t)
+        )
+
+    def dispatch(self, t: float) -> None:
+        """A scan call is dispatched at ``t``: the host stretch ends."""
+        if self._init is not None:
+            self._init.close(t)
+            self._init = None
+        self.serial_s += t - self._free
+
+    def synced(self, t: float, reads: int) -> None:
+        """``reads`` device→host reads returned at ``t``: a stretch starts."""
+        self.syncs += reads
+        self._free = t
+
+    finish = dispatch
 
 
 class ScanDriver:
@@ -1291,7 +1327,9 @@ class ScanDriver:
 
     # -- ring (file) mode --------------------------------------------------
     def _run_ring(
-        self, on_assign: Callable[[int, np.ndarray, np.ndarray], None]
+        self,
+        on_assign: Callable[[int, np.ndarray, np.ndarray], None],
+        host: Optional[HostSerial] = None,
     ) -> DriveResult:
         src, core = self.source, self.core
         z = self.z
@@ -1316,6 +1354,9 @@ class ScanDriver:
         cursors = np.zeros((z,), np.int64)
         trace = self.trace
         traced = trace.enabled
+        host = HostSerial() if host is None else host
+        # The recalibration reads the device's score-row counter.
+        recal_reads = int(self.has_budget and not self.fixed_cost)
         done_before = 0
         try:
             buf = src.alloc()
@@ -1327,14 +1368,20 @@ class ScanDriver:
                     f"{self.m_per} assigned after {iters} calls"
                 )
                 buf = src.refill(buf, cursors)
+                # Dispatch -> speculative refill -> the per-call sync ->
+                # emit: the whole host wait for scan call k.
+                t_call = time.perf_counter()
+                host.dispatch(t_call)
+                call = trace.span("scan-call", "scan").open(t_call)
                 if traced:
-                    t_call = time.perf_counter()
                     cc0 = scan_compile_counts()["run_scan_ring"]
+                dispatch = trace.span("dispatch", "dispatch").open(t_call)
                 (carry, buf), out = _run_scan_ring(
                     (carry, buf), self._m_real_j, self._allowed_j,
                     self._caps_j,
                     core=core, n_steps=S, n_shards=self.n_shards,
                 )
+                dispatch.close()
                 if pipelined:
                     # Safe without syncing: the in-flight call advances
                     # every unfinished instance by >= S assignments, so rows
@@ -1342,6 +1389,7 @@ class ScanDriver:
                     # ring orders this write after the in-flight scan.
                     lb = np.minimum(assigned + S, self.m_per)
                     buf = src.refill(buf, lb, speculative=True)
+                sync = trace.span("sync", "sync").open()
                 # staticcheck: disable=SC003 ring-mode termination: ONE assigned-counter sync per scan call, amortized over S steps
                 assigned = np.asarray(carry.assigned).astype(np.int64)
                 # staticcheck: disable=SC003 next refill needs the host cursor to size disk reads; same single sync point per call
@@ -1350,27 +1398,28 @@ class ScanDriver:
                 sidx = np.asarray(out.sidx).reshape(z, -1)
                 # staticcheck: disable=SC003 same spill materialization as sidx above
                 pout = np.asarray(out.p).reshape(z, -1)
+                t_synced = time.perf_counter()
+                sync.close(t_synced)
+                host.synced(t_synced, 4)
+                emit = trace.span("emit", "emit").open(t_synced)
                 for i in range(z):
                     live = sidx[i] >= 0
                     if live.any():
                         on_assign(
                             i, sidx[i][live].astype(np.int64), pout[i][live]
                         )
+                emit.close()
                 if traced:
-                    # Dispatch -> speculative refill -> the per-call sync ->
-                    # emit: the whole host wait for scan call k. `rows` stays
-                    # an np scalar (no int() on synced mirrors on this hot
-                    # path); the exporter unwraps it.
+                    # `rows` stays an np scalar (no int() on synced mirrors
+                    # on this hot path); the exporter unwraps it.
                     done = assigned.sum()
-                    trace.add_span(
-                        "scan-call", "scan", t_call, time.perf_counter(),
-                        attrs=dict(call=iters, steps=S,
-                                   rows=done - done_before,
-                                   compiled=scan_compile_counts()[
-                                       "run_scan_ring"] > cc0),
-                    )
+                    call.set(call=iters, steps=S, rows=done - done_before,
+                             compiled=scan_compile_counts()[
+                                 "run_scan_ring"] > cc0)
                     done_before = done
+                call.close()
                 carry = self._recalibrate(carry, t0)
+                host.syncs += recal_reads
             assert (cursors <= src.hi).all(), (
                 f"scan cursors {cursors} overran uploaded rows {src.hi}"
             )
@@ -1381,16 +1430,18 @@ class ScanDriver:
         self.ring_handle = RingHandle(
             buf=buf, hi=src.hi.copy(), B=src.B, z=z, m_per=self.m_per.copy()
         )
-        return self._result(
-            carry, wall, sidx=None, p=None, w_trace=None,
-            scan_calls=iters, h2d_rows=src.h2d_rows, h2d_bytes=src.h2d_bytes,
-            buffer_rows=src.B, steps_per_call=S,
-            h2d_wait_s=src.h2d_wait_s, prefetch_depth=src.prefetch,
-            refill_spans=src.refill_spans,
-            spans_prestaged=src.spans_prestaged,
-            spans_missed=src.spans_missed,
-            prestage_wall_s=src.prestage_wall_s,
-        )
+        # The final counters are device→host reads too, after the loop.
+        with trace.span("result", "host"):
+            return self._result(
+                carry, wall, sidx=None, p=None, w_trace=None,
+                scan_calls=iters, h2d_rows=src.h2d_rows,
+                h2d_bytes=src.h2d_bytes, buffer_rows=src.B, steps_per_call=S,
+                h2d_wait_s=src.h2d_wait_s, prefetch_depth=src.prefetch,
+                refill_spans=src.refill_spans,
+                spans_prestaged=src.spans_prestaged,
+                spans_missed=src.spans_missed,
+                prestage_wall_s=src.prestage_wall_s,
+            )
 
     def _result(
         self,
@@ -1444,6 +1495,7 @@ class ScanDriver:
         *,
         n_chunks: int = 8,
         on_assign: Optional[Callable[[int, np.ndarray, np.ndarray], None]] = None,
+        host: Optional[HostSerial] = None,
     ) -> DriveResult:
         """Drive the scan to completion.
 
@@ -1451,12 +1503,13 @@ class ScanDriver:
         (+ drain) and return the collected step outputs; file sources loop
         refill→scan until every instance has assigned its stream, emitting
         finished placements through ``on_assign(i, local_idx, p)`` (required
-        — the file path never holds O(m) outputs).
+        — the file path never holds O(m) outputs) and accounting host-serial
+        time and syncs into ``host``.
         """
         if self.source.resident:
             return self._run_resident(n_chunks)
         assert on_assign is not None, "file-mode driving requires on_assign"
-        return self._run_ring(on_assign)
+        return self._run_ring(on_assign, host)
 
     def stats_base(self, res: DriveResult, instance: int) -> dict:
         """The shared per-instance stat fields every caller reports."""

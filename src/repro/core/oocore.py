@@ -57,7 +57,7 @@ import numpy as np
 
 from repro.core import baselines
 from repro.core.adwise import WarmState
-from repro.core.driver import FileSource, RingHandle, ScanDriver
+from repro.core.driver import FileSource, HostSerial, RingHandle, ScanDriver
 from repro.core.restream import TpslCore, VertexClusteringState, _pack_clusters
 from repro.core.spotlight import _SPOTLIGHT_INCOMPATIBLE, spread_mask
 from repro.core.types import AdwiseConfig, PartitionResult
@@ -170,6 +170,7 @@ def _drive_core(
     prefetch: Optional[int] = None,
     resume: Optional[RingHandle] = None,
     trace=None,
+    host: Optional[HostSerial] = None,
 ) -> tuple[List[dict], Optional[RingHandle]]:
     """Feed z instance streams through any step-core's scan in a bounded
     device-resident ring buffer — a thin caller of
@@ -180,8 +181,9 @@ def _drive_core(
     ``write_assign(i, local_idx, p)`` receives finished placements.
     ``prev_read[i](start, count)`` supplies the prior pass's placements for
     buffered re-streaming revocation; ``resume`` adopts the previous pass's
-    ring under the cross-pass shared-buffer contract. Returns per-instance
-    stats dicts plus this pass's :class:`RingHandle` for the next one.
+    ring under the cross-pass shared-buffer contract; ``host`` accumulates
+    the caller's host-serial time and syncs. Returns per-instance stats
+    dicts plus this pass's :class:`RingHandle` for the next one.
     """
     z = len(readers)
     m_per = np.array([r.num_edges for r in readers], dtype=np.int64)
@@ -198,7 +200,7 @@ def _drive_core(
     )
     drv = ScanDriver(source, core, num_vertices, allowed=allowed, warm=warm,
                      backend=backend, trace=trace)
-    res = drv.run(on_assign=write_assign)
+    res = drv.run(on_assign=write_assign, host=host)
     stats = []
     for i in range(z):
         assert int(res.assigned[i]) == int(m_per[i]), (
@@ -302,6 +304,7 @@ def _run_two_phase_chunks(
     prefetch: Optional[int] = None,
     cluster_slack: float = 1.25,
     trace=None,
+    host: Optional[HostSerial] = None,
     **cfg,
 ) -> List[dict]:
     """2PS / 2PS-L over z per-instance readers: chunked degree pass →
@@ -365,7 +368,7 @@ def _run_two_phase_chunks(
         per_stats, _ = _drive_core(
             readers, num_vertices, core, write_assign=write_assign,
             chunk_edges=chunk_edges, allowed=allowed, warm=warms,
-            backend=backend, prefetch=prefetch, trace=trace,
+            backend=backend, prefetch=prefetch, trace=trace, host=host,
         )
     wall = time.perf_counter() - t0
     return [
@@ -409,6 +412,7 @@ def _run_restream_chunks(
     backend: str = "auto",
     prefetch: Optional[int] = None,
     trace=None,
+    host: Optional[HostSerial] = None,
     **adwise_cfg,
 ) -> dict:
     """n-pass re-streaming where every pass re-reads the stream from disk and
@@ -440,7 +444,7 @@ def _run_restream_chunks(
                 lambda sp: lambda i, idx, p: sp.write(offsets[i] + idx, p)
             )(spill),
             chunk_edges=chunk_edges, allowed=allowed, backend=backend,
-            prefetch=prefetch, trace=trace,
+            prefetch=prefetch, trace=trace, host=host,
         )
     else:
         if z > 1:
@@ -530,7 +534,7 @@ def _run_restream_chunks(
             )(spill),
             chunk_edges=chunk_edges, allowed=allowed, warm=warms,
             prev_read=prev_read, backend=backend,
-            prefetch=prefetch, resume=handle, trace=trace,
+            prefetch=prefetch, resume=handle, trace=trace, host=host,
         )
         pm = metrics_of(spill)
         dr, db, dc = h2d_of(pass_stats)
@@ -670,8 +674,12 @@ def partition_file(
 
     Returns a PartitionResult whose ``assign`` is a read-only memmap over the
     final spill file (stats carry ``spill_path``) — **bit-identical** to the
-    in-memory registry / spotlight path for the same inputs.
+    in-memory registry / spotlight path for the same inputs. Stats also
+    carry ``host_serial_s``, the host time of the call with no scan call in
+    flight, and ``host_syncs``, the device→host reads of its stepping loops
+    (:class:`repro.core.driver.HostSerial`).
     """
+    t_entry = time.perf_counter()
     m = reader.num_edges
     n = reader.num_vertices
     if z < 1:
@@ -694,13 +702,16 @@ def partition_file(
                  h2d_rows=0, h2d_bytes=0, scan_calls=0, buffer_rows=0,
                  h2d_wait_s=0.0, prefetch_depth=0, refill_spans=0,
                  spans_prestaged=0, spans_missed=0, prestage_wall_s=0.0,
-                 unassigned=0),
+                 host_serial_s=0.0, host_syncs=0, unassigned=0),
         )
     if spill_dir is None:
         spill_dir = tempfile.mkdtemp(prefix="adwise-oocore-")
     os.makedirs(spill_dir, exist_ok=True)
 
     tr = resolve_tracer(trace)
+    whole = tr.span("partition_file", "phase", strategy=strategy, k=k, z=z,
+                    m=m).open(t_entry)
+    host = HostSerial(tr, t_entry)
     rows_before = getattr(reader, "rows_read", 0)
     io_before = getattr(reader, "read_seconds", 0.0)
     final = _Spill(os.path.join(spill_dir, "assign.i32"), m)
@@ -740,7 +751,7 @@ def partition_file(
             per_stats, _ = _drive_core(
                 readers, n, acfg, write_assign=write_core,
                 chunk_edges=chunk_edges, allowed=allowed, backend=backend,
-                prefetch=prefetch, trace=trace,
+                prefetch=prefetch, trace=trace, host=host,
             )
             stats = dict(per_stats[0], stream_reads=1)
             if z > 1:
@@ -749,7 +760,7 @@ def partition_file(
             stats = _run_restream_chunks(
                 readers, n, k, seed, chunk_edges, spill_dir, m, offsets, final,
                 allowed=allowed, backend=backend, prefetch=prefetch,
-                trace=trace, **cfg,
+                trace=trace, host=host, **cfg,
             )
             if z > 1:
                 stats.update(name="spotlight-adwise-restream", z=z, spread=spread)
@@ -766,7 +777,7 @@ def partition_file(
         per_stats = _run_two_phase_chunks(
             readers, n, k, seed, chunk_edges, write_core,
             variant=strategy, allowed=allowed, backend=backend,
-            prefetch=prefetch, trace=trace, **cfg,
+            prefetch=prefetch, trace=trace, host=host, **cfg,
         )
         stats = per_stats[0]
         if z > 1:
@@ -790,7 +801,7 @@ def partition_file(
         per_stats, _ = _drive_core(
             readers, n, core, write_assign=write_core,
             chunk_edges=chunk_edges, allowed=allowed, backend=backend,
-            prefetch=prefetch, trace=trace,
+            prefetch=prefetch, trace=trace, host=host,
         )
         stats = dict(per_stats[0], stream_reads=1)
         if z > 1:
@@ -838,18 +849,22 @@ def partition_file(
         unassigned=0,
     )
     # Chunked completeness check (no O(m) temporary; raises even under -O).
-    with tr.span("spill-verify", cat="phase", m=m):
-        neg = 0
-        for start in range(0, m, chunk_edges):
-            a = final.read(start, min(chunk_edges, m - start))
-            neg += int((a < 0).sum())
+    verify = tr.span("spill-verify", "phase", m=m).open()
+    neg = 0
+    for start in range(0, m, chunk_edges):
+        a = final.read(start, min(chunk_edges, m - start))
+        neg += int((a < 0).sum())
+    t_end = time.perf_counter()
+    verify.close(t_end)
     if neg:
         raise RuntimeError(f"partition_file left {neg} of {m} edges unassigned")
+    host.finish(t_end)
+    stats.update(host_serial_s=host.serial_s, host_syncs=host.syncs)
+    # The counters ride on the span, and on its profiler annotation.
+    whole.set(host_serial_s=host.serial_s, host_syncs=host.syncs,
+              scan_calls=stats.get("scan_calls", 0))
+    whole.close()
     if tr.enabled:
-        tr.add_span(
-            "partition_file", "phase", t0, time.perf_counter(),
-            attrs=dict(strategy=strategy, k=k, z=z, m=m),
-        )
         stats["trace_summary"] = tr.summary().as_dict()
     return PartitionResult(final.flush_readonly(), stats)
 

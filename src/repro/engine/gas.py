@@ -104,22 +104,27 @@ def superstep_program(
     ``edges`` (kp, E, 2), ``evalid`` (kp, E) and ``replicas_t`` (kp, V) are
     sharded over ``parts``; ``state`` (V, d) and ``degrees`` (V,) are
     replicated. Gather runs per device, then the replica-masked accumulators
-    are combined across ``parts`` (psum for ``add``, pmin for ``min``).
+    are combined across ``parts`` (psum for ``add``, pmin for ``min``). The
+    phases carry the scopes ``engine.gather``, ``engine.combine`` and
+    ``engine.apply`` in the program's op metadata (the profiler's names).
     """
     if combine not in ("add", "min"):
         raise ValueError(combine)
 
     def step(state, edges, evalid, replicas_t, degrees):
-        acc = gather_local(
-            edges, evalid, state, degrees, msg_fn, num_vertices, agg=combine
-        )
-        if combine == "add":
-            local = (acc * replicas_t[:, :, None]).sum(axis=0)  # mask to replicas
-            synced = jax.lax.psum(local, "parts")
-        else:
-            local = jnp.where(replicas_t[:, :, None] > 0, acc, BIG).min(axis=0)
-            synced = jax.lax.pmin(local, "parts")
-        return apply_fn(state, synced, degrees)
+        with jax.named_scope("engine.gather"):
+            acc = gather_local(
+                edges, evalid, state, degrees, msg_fn, num_vertices, agg=combine
+            )
+        with jax.named_scope("engine.combine"):
+            if combine == "add":
+                local = (acc * replicas_t[:, :, None]).sum(axis=0)  # mask to replicas
+                synced = jax.lax.psum(local, "parts")
+            else:
+                local = jnp.where(replicas_t[:, :, None] > 0, acc, BIG).min(axis=0)
+                synced = jax.lax.pmin(local, "parts")
+        with jax.named_scope("engine.apply"):
+            return apply_fn(state, synced, degrees)
 
     return jax.jit(compat.shard_map(
         step,
@@ -158,8 +163,8 @@ def make_superstep(
     slabs, and the psum stalls on the stragglers). The cross-partition
     combine is permutation-invariant, so reordering slabs never changes
     results. The returned callable exposes the placement as
-    ``.slab_occupancy`` (real slabs per device) and the traced superstep
-    span carries it for Perfetto visibility.
+    ``.slab_occupancy`` (real slabs per device); its ``superstep`` span
+    carries it for Perfetto visibility.
     """
     v, k = g.num_vertices, g.k
     n_shards = int(mesh.devices.size)
@@ -208,22 +213,16 @@ def make_superstep(
     )
     degrees = jax.device_put(g.degrees, NamedSharding(mesh, P()))
 
-    def superstep(state):
-        return program(state, edges_d, evalid_d, repl_t, degrees)
-
     slab_occupancy = tuple(int(c) for c in occupancy)
     tr = resolve_tracer(trace)
-    if not tr.enabled:
-        superstep.slab_occupancy = slab_occupancy
-        return superstep
 
-    # Tracing wraps the jitted call from the host side: the span covers
-    # dispatch only (no block_until_ready, no added sync) and lives outside
-    # the traced program, so the compiled superstep is unchanged.
-    def traced_superstep(state):
+    # The span wraps the jitted call from the host side: it covers dispatch
+    # only (no block_until_ready, no added sync) and lives outside the
+    # traced program, so the compiled superstep is unchanged.
+    def superstep(state):
         with tr.span("superstep", cat="engine", k=k, combine=combine,
                      n_shards=n_shards, slab_occupancy=list(slab_occupancy)):
-            return superstep(state)
+            return program(state, edges_d, evalid_d, repl_t, degrees)
 
-    traced_superstep.slab_occupancy = slab_occupancy
-    return traced_superstep
+    superstep.slab_occupancy = slab_occupancy
+    return superstep

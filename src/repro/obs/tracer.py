@@ -11,36 +11,51 @@ virtual lanes (restream passes use ``restream-pass-<j>``). Nesting is
 by timestamp containment per track — exactly how Perfetto renders
 Chrome trace events — so spans carry no explicit parent pointers.
 
-Two recording paths, by temperature:
+Profiler annotations
+--------------------
+While a ``jax.profiler`` session records, every span opened with
+``span()`` — on a :class:`Tracer` or on the null tracer — is also a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>``: the same span
+sites, on the profiler's clock, beside the device's timeline. Its attrs
+ride along as the annotation's metadata (``set()`` adds to both). Whether
+a span records into the tracer depends only on ``tracer.enabled``.
 
-* ``with tracer.span(name, cat=...):`` — context manager, for coarse
-  spans (passes, phases, supersteps, CLI sections).
-* ``tracer.add_span(name, cat, t0, t1)`` — explicit timestamps, for hot
-  loops. The caller takes ``perf_counter()`` itself, which lets a span
-  share the *exact* float pair that also feeds a stats counter (e.g. the
-  blocking-refill span reuses the timestamps behind ``h2d_wait_s``), so
-  category wall totals reconcile with the scalar counters bit-for-bit.
+Two recording paths:
+
+* ``with tracer.span(name, cat=...):`` — a scope. Hot sites whose
+  timestamps also feed a stats counter pass the floats themselves:
+  ``sp = tracer.span(...).open(t0)`` ... ``sp.close(t1)``. The span then
+  shares the *exact* float pair with the counter (the blocking-refill span
+  reuses the timestamps behind ``h2d_wait_s``), so category wall totals
+  reconcile with the scalar counters bit-for-bit.
+* ``tracer.add_span(name, cat, t0, t1)`` — a finished interval, recorded
+  into the tracer only: an annotation has to be open while the work runs,
+  so this path never reaches the profiler.
 
 Overhead contract
 -----------------
 Hot paths gate on ``tracer.enabled`` (a plain class attribute — one
-attribute load) and only then take timestamps or build attr dicts. With
-tracing disabled callers hold :data:`NULL_TRACER`, a module-level
-singleton whose ``span()`` returns a shared no-op span object: the
-disabled path allocates nothing per call and records nothing, which is
-what lets the driver keep a tracer on its hottest loops unconditionally.
+attribute load) before building attr dicts. With tracing disabled
+callers hold :data:`NULL_TRACER`, a module-level singleton whose
+``span()`` costs one ``TraceAnnotation.is_enabled()`` check and, with no
+profiler session, returns a shared no-op span object: the disabled path
+allocates nothing per call and records nothing, which is what lets the
+driver keep a tracer on its hottest loops unconditionally.
 
-Everything here is host-side and stdlib-only by design: spans must wrap
-dispatch and host waits only — never values still on device. Calling the
-tracer *inside* a jit-traced step closure would concretize tracers and
-add a per-step host sync; ``tools/staticcheck`` rule SC003 flags exactly
-that (see ``tools/staticcheck/README.md``).
+Everything here is host-side: spans must wrap dispatch and host waits
+only — never values still on device. Calling the tracer *inside* a
+jit-traced step closure would concretize tracers and add a per-step host
+sync; ``tools/staticcheck`` rule SC003 flags exactly that (see
+``tools/staticcheck/README.md``). Device phases are named inside the
+programs with ``jax.named_scope`` instead.
 """
 from __future__ import annotations
 
 import threading
 import time
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Tracer",
@@ -106,13 +121,17 @@ class TraceSummary(NamedTuple):
 
 
 class _Span:
-    """Context-manager span; records itself on ``__exit__``."""
+    """A span scope: ``with`` it, or ``open()``/``close()`` it with
+    caller-taken timestamps. While a profiler session records it is also
+    the annotation ``repro.<name>``; it records into its tracer on close
+    when the tracer is enabled. ``t0``/``t1`` hold its interval."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_track", "_attrs", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_track", "_attrs", "_note",
+                 "t0", "t1")
 
     def __init__(
         self,
-        tracer: "Tracer",
+        tracer: Any,
         name: str,
         cat: str,
         track: Optional[str],
@@ -123,35 +142,57 @@ class _Span:
         self._cat = cat
         self._track = track
         self._attrs = attrs
-        self._t0 = 0.0
+        self._note: Optional[TraceAnnotation] = None
+        self.t0 = 0.0
+        self.t1 = 0.0
 
     def set(self, **attrs: Any) -> "_Span":
         """Attach attrs discovered mid-span (e.g. per-pass quality)."""
         self._attrs.update(attrs)
+        if self._note is not None:
+            self._note.set_metadata(**attrs)
         return self
+
+    def open(self, t0: Optional[float] = None) -> "_Span":
+        if TraceAnnotation.is_enabled():
+            self._note = TraceAnnotation("repro." + self._name, **self._attrs)
+            self._note.__enter__()
+        self.t0 = time.perf_counter() if t0 is None else t0
+        return self
+
+    def close(self, t1: Optional[float] = None) -> None:
+        self.t1 = time.perf_counter() if t1 is None else t1
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
+            self._note = None
+        if self._tracer.enabled:
+            self._tracer.add_span(
+                self._name, self._cat, self.t0, self.t1,
+                track=self._track, attrs=self._attrs,
+            )
 
     def __enter__(self) -> "_Span":
-        self._t0 = time.perf_counter()
-        return self
+        return self.open()
 
     def __exit__(self, *exc: Any) -> None:
-        self._tracer.add_span(
-            self._name,
-            self._cat,
-            self._t0,
-            time.perf_counter(),
-            track=self._track,
-            attrs=self._attrs,
-        )
+        self.close()
 
 
 class _NullSpan:
     """Shared no-op span: zero allocation on the disabled path."""
 
     __slots__ = ()
+    t0 = 0.0
+    t1 = 0.0
 
     def set(self, **attrs: Any) -> "_NullSpan":
         return self
+
+    def open(self, t0: Optional[float] = None) -> "_NullSpan":
+        return self
+
+    def close(self, t1: Optional[float] = None) -> None:
+        return None
 
     def __enter__(self) -> "_NullSpan":
         return self
@@ -189,7 +230,7 @@ class Tracer:
     def span(
         self, name: str, cat: str = "misc", track: Optional[str] = None, **attrs: Any
     ) -> _Span:
-        """Open a context-manager span (coarse path)."""
+        """A span scope (see :class:`_Span`)."""
         return _Span(self, name, cat, track, attrs)
 
     def add_span(
@@ -201,7 +242,8 @@ class Tracer:
         track: Optional[str] = None,
         attrs: Optional[Dict[str, Any]] = None,
     ) -> None:
-        """Record a finished interval with caller-taken timestamps."""
+        """Record a finished interval with caller-taken timestamps (into
+        this tracer only; see the module docstring)."""
         rec = SpanRecord(
             name,
             cat,
@@ -268,8 +310,9 @@ class Tracer:
 
 class NullTracer:
     """API-compatible no-op. ``enabled`` is False; hot paths branch on it
-    and skip even timestamp-taking; the coarse path gets a shared no-op
-    span object, so the disabled path allocates nothing per call."""
+    and skip building attrs; ``span()`` gets a shared no-op span object, so
+    the disabled path allocates nothing per call. While a profiler session
+    records, ``span()`` still opens the ``repro.<name>`` annotation."""
 
     __slots__ = ()
     enabled: bool = False
@@ -277,7 +320,9 @@ class NullTracer:
 
     def span(
         self, name: str, cat: str = "misc", track: Optional[str] = None, **attrs: Any
-    ) -> _NullSpan:
+    ) -> Any:
+        if TraceAnnotation.is_enabled():
+            return _Span(self, name, cat, track, attrs)
         return _NULL_SPAN
 
     def add_span(
