@@ -530,8 +530,8 @@ def partition_stream_batched(
       A list of z :class:`PartitionResult`; entry i's ``assign`` covers
       instance i's real (un-padded) stream in local order. With z == 1 and
       identical inputs the assignment is bit-identical to
-      :func:`partition_stream` — the batched step function is the same
-      trace, vmapped.
+      :func:`partition_stream` — both run the same step function on the
+      lone instance, unbatched.
 
     Length bucketing: instances are grouped by ``ceil_pow2(m_i)`` and each
     bucket runs as its own batched scan padded to

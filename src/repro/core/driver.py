@@ -24,10 +24,12 @@ of that once, over a *pluggable chunk source*:
   scan call's worst-case consumption (``window_max + S * assign_batch``
   rows per S-step call — the same cursor-advance bound PR 4 proved).
 
-Both modes run the *same* vmapped (optionally shard_mapped) step function —
-the per-step math is one trace, so the file path stays bit-identical to the
-in-memory path (the registry-wide parity tests in tests/test_oocore.py are
-the oracle, plus the ring-specific property tests in tests/test_driver.py).
+Both modes run the *same* step function — the per-step math is one trace,
+so the file path stays bit-identical to the in-memory path (the
+registry-wide parity tests in tests/test_oocore.py are the oracle, plus the
+ring-specific property tests in tests/test_driver.py). A single instance
+runs it unbatched; z > 1 instances run it vmapped (optionally shard_mapped)
+— the ``scan_path`` stat says which.
 
 Step-cores
 ----------
@@ -150,6 +152,7 @@ __all__ = [
     "resolve_backend",
     "resolve_prefetch",
     "scan_compile_counts",
+    "scan_path",
     "PREFETCH_ENV",
 ]
 
@@ -357,7 +360,7 @@ class AdwiseCore(StepCore):
 
 
 # ----------------------------------------------------------------------------
-# Scan executors: one vmapped program for all z instances, resident or ring
+# Scan executors: one program for all z instances, resident or ring
 # ----------------------------------------------------------------------------
 
 
@@ -389,6 +392,57 @@ def _shard_over_instances(
     )
 
 
+def scan_path(z: int, n_shards: int) -> str:
+    """How the scan executors run z instances over ``n_shards`` devices:
+    ``"single"`` steps a lone unsharded instance unbatched, ``"vmap"``
+    batches the instances (shard_mapped when ``n_shards > 1``)."""
+    return "single" if z == 1 and n_shards <= 1 else "vmap"
+
+
+def _batched(one: Callable[..., Any], n_shards: int, *args: Any) -> Any:
+    """``one`` vmapped over the leading instance axis of every leaf of
+    ``args``, shard_mapped over ``n_shards`` devices when > 1."""
+    batched = jax.vmap(one)
+    if n_shards > 1:
+        batched = _shard_over_instances(batched, n_shards, len(args))
+    return batched(*args)
+
+
+def _over_instances(one: Callable[..., Any], n_shards: int, *args: Any) -> Any:
+    """Run ``one`` per instance along the leading axis of ``args``.
+
+    A lone instance runs ``one`` on the bare instance: the scan then
+    carries its V-sized tables in the layout its scatters write, and the
+    unit axis is dropped and restored once per call instead of on every
+    step of the loop. Otherwise the instances run :func:`_batched`.
+    """
+    z = jax.tree.leaves(args)[0].shape[0]
+    if scan_path(z, n_shards) == "vmap":
+        return _batched(one, n_shards, *args)
+    out = one(*jax.tree.map(lambda x: x[0], args))
+    return jax.tree.map(lambda x: x[None], out)
+
+
+def _scan_resident_one(
+    carry: Any, stream: Any, m_real: Any, allowed: Any, cap: Any, prev: Any,
+    *, core: StepCore, n_steps: int,
+) -> Any:
+    """One instance's scan over its resident stream."""
+    step = core.make_step(stream, m_real, allowed, cap, prev)
+    return jax.lax.scan(step, carry, None, length=n_steps)
+
+
+def _scan_ring_one(
+    carry_buf: Any, m_real: Any, allowed: Any, cap: Any,
+    *, core: StepCore, n_steps: int,
+) -> Any:
+    """One instance's scan over its ring; the ring is returned untouched."""
+    carry, buf = carry_buf
+    step = core.make_step(buf.uv, m_real, allowed, cap, buf.prev)
+    carry, outs = jax.lax.scan(step, carry, None, length=n_steps)
+    return (carry, buf), outs
+
+
 @partial(
     jax.jit,
     donate_argnums=(0,),
@@ -407,17 +461,10 @@ def _run_scan_resident(
     n_shards: int = 0,
 ) -> Any:
     """All z instance scans as ONE program over a fully resident stream."""
-
-    def one(
-        carry: Any, stream: Any, m_real: Any, allowed: Any, cap: Any, prev: Any
-    ) -> Any:
-        step = core.make_step(stream, m_real, allowed, cap, prev)
-        return jax.lax.scan(step, carry, None, length=n_steps)
-
-    batched = jax.vmap(one)
-    if n_shards > 1:
-        batched = _shard_over_instances(batched, n_shards, 6)
-    return batched(carry, streams, m_real, allowed, cap, prev_assign)
+    one = partial(_scan_resident_one, core=core, n_steps=n_steps)
+    return _over_instances(
+        one, n_shards, carry, streams, m_real, allowed, cap, prev_assign
+    )
 
 
 @partial(
@@ -438,17 +485,8 @@ def _run_scan_ring(
     """Ring-mode scan: the stream buffer rides in the donated carry and is
     returned untouched, so XLA aliases it across calls (zero copies, zero
     re-upload)."""
-
-    def one(carry_buf: Any, m_real: Any, allowed: Any, cap: Any) -> Any:
-        carry, buf = carry_buf
-        step = core.make_step(buf.uv, m_real, allowed, cap, buf.prev)
-        carry, outs = jax.lax.scan(step, carry, None, length=n_steps)
-        return (carry, buf), outs
-
-    batched = jax.vmap(one)
-    if n_shards > 1:
-        batched = _shard_over_instances(batched, n_shards, 4)
-    return batched(carry_buf, m_real, allowed, cap)
+    one = partial(_scan_ring_one, core=core, n_steps=n_steps)
+    return _over_instances(one, n_shards, carry_buf, m_real, allowed, cap)
 
 
 @partial(
@@ -1048,6 +1086,7 @@ class DriveResult(NamedTuple):
     r_sel: int
     backend: str
     n_shards: int
+    scan_path: str  # "single" (one unbatched instance) or "vmap"
     scan_calls: int
     h2d_rows: int
     h2d_bytes: int
@@ -1209,6 +1248,7 @@ class ScanDriver:
             carry = core.set_cost(carry, cost_per_score, z)
         self.carry = carry
         self.backend, self.n_shards = resolve_backend(backend, z)
+        self.scan_path = scan_path(z, self.n_shards)
         self._m_real_j = jnp.asarray(self.m_per.astype(np.int32))
         self._allowed_j = jnp.asarray(allowed_np)
         self._caps_j = jnp.asarray(caps)
@@ -1477,6 +1517,7 @@ class ScanDriver:
             r_sel=self.r_sel,
             backend=self.backend,
             n_shards=self.n_shards,
+            scan_path=self.scan_path,
             scan_calls=scan_calls,
             h2d_rows=int(h2d_rows),
             h2d_bytes=int(h2d_bytes),
@@ -1526,6 +1567,7 @@ class ScanDriver:
             r_sel=res.r_sel,
             modeled_cost_per_score=float(res.cost_per_score[instance]),
             scan_calls=res.scan_calls,
+            scan_path=res.scan_path,
             h2d_rows=res.h2d_rows,
             h2d_bytes=res.h2d_bytes,
             buffer_rows=res.buffer_rows,

@@ -607,6 +607,7 @@ def _run_restream_chunks(
         spans_missed=spans_missed,
         prestage_wall_s=prestage_wall_s,
         scan_calls=scan_calls,
+        scan_path=pass_stats[0].get("scan_path"),
         buffer_rows=buffer_rows,
         wall_time_s=time.perf_counter() - t0,
     )
@@ -860,9 +861,13 @@ def partition_file(
         raise RuntimeError(f"partition_file left {neg} of {m} edges unassigned")
     host.finish(t_end)
     stats.update(host_serial_s=host.serial_s, host_syncs=host.syncs)
-    # The counters ride on the span, and on its profiler annotation.
-    whole.set(host_serial_s=host.serial_s, host_syncs=host.syncs,
-              scan_calls=stats.get("scan_calls", 0))
+    # The counters ride on the span, and on its profiler annotation; a
+    # scanning strategy's executor path rides beside them.
+    counters = dict(host_serial_s=host.serial_s, host_syncs=host.syncs,
+                    scan_calls=stats.get("scan_calls", 0))
+    if stats.get("scan_path"):
+        counters["scan_path"] = stats["scan_path"]
+    whole.set(**counters)
     whole.close()
     if tr.enabled:
         stats["trace_summary"] = tr.summary().as_dict()

@@ -4,7 +4,10 @@ resident full-upload path, the host→device traffic accounting, and the
 double-buffered refill pipeline (read-ahead worker determinism/teardown)."""
 import threading
 import time
+from functools import partial
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings
@@ -16,13 +19,17 @@ from repro.core import (
     run_partitioner,
     spotlight_partition,
 )
-from repro.core.adwise import partition_stream
+from repro.core import driver
+from repro.core.adwise import partition_stream, partition_stream_batched
+from repro.core.baselines import HdrfCore
 from repro.core.driver import (
+    AdwiseCore,
     FileSource,
     ResidentSource,
     ScanDriver,
     resolve_backend,
     resolve_prefetch,
+    scan_path,
 )
 from repro.graph import rmat
 from repro.graph.io import EdgeFileReader, write_edge_file
@@ -360,3 +367,107 @@ def test_driver_rejects_file_mode_without_sink(rmat_file):
         drv = ScanDriver(src, cfg, n)
         with pytest.raises(AssertionError, match="on_assign"):
             drv.run()
+
+
+# ----------------------------------------------------------------------------
+# Executor paths: a lone instance stepped unbatched == the vmapped program
+# ----------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def kilo_file(tmp_path_factory):
+    edges, n = rmat(10, 2000, seed=14)
+    path = str(tmp_path_factory.mktemp("paths") / "rmat10.adw")
+    write_edge_file(path, edges, n)
+    return path, edges, n
+
+
+def _core(name, n):
+    if name == "adwise":
+        return AdwiseCore(cfg=AdwiseConfig(k=K, window_max=8), num_vertices=n)
+    return HdrfCore(num_vertices=n, k=K, seed=3)
+
+
+def _vmapped(one, core, n_steps):
+    """The executors' batched path on its own: the program every instance
+    count ran before a lone instance was stepped unbatched."""
+    one = partial(one, core=core, n_steps=n_steps)
+    return jax.jit(lambda *args: driver._batched(one, 0, *args))
+
+
+def _assert_same(got, want):
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(g, w)
+
+
+@pytest.mark.parametrize("core_name", ["adwise", "hdrf"])
+def test_single_instance_ring_scan_matches_vmap(kilo_file, core_name):
+    """At z = 1 `_run_scan_ring` steps the bare instance; its carry, ring
+    and step outputs are bit-identical to the vmapped program's, call
+    after call of a file-backed ring scan."""
+    path, _, n = kilo_file
+    core = _core(core_name, n)
+    with EdgeFileReader(path) as r, FileSource(
+        [r], chunk_edges=256, core=core, prefetch=0
+    ) as src:
+        drv = ScanDriver(src, core, n)
+        assert drv.scan_path == "single"
+        steps = src.scan_steps
+        batched = _vmapped(driver._scan_ring_one, core, steps)
+        args = (drv._m_real_j, drv._allowed_j, drv._caps_j)
+        carry, buf = drv.carry, src.alloc()
+        cursors = np.zeros((1,), np.int64)
+        for _ in range(3):
+            buf = src.refill(buf, cursors)
+            want = batched((carry, buf), *args)
+            got = driver._run_scan_ring(
+                (carry, buf), *args, core=core, n_steps=steps, n_shards=0
+            )
+            _assert_same(got, want)
+            (carry, buf), _ = got
+            cursors = np.asarray(carry.cursor).astype(np.int64)
+        assert int(carry.assigned[0]) > 0
+
+
+@pytest.mark.parametrize("core_name", ["adwise", "hdrf"])
+def test_single_instance_resident_scan_matches_vmap(kilo_file, core_name):
+    """The resident executor takes the same rule as the ring one."""
+    _, edges, n = kilo_file
+    core = _core(core_name, n)
+    m = len(edges)
+    src = ResidentSource(edges[None], np.array([m]))
+    drv = ScanDriver(src, core, n)
+    assert drv.scan_path == "single"
+    steps = 300
+    batched = _vmapped(driver._scan_resident_one, core, steps)
+    args = (jnp.asarray(src.streams), drv._m_real_j, drv._allowed_j,
+            drv._caps_j, jnp.full((1, m), -1, jnp.int32))
+    carry = drv.carry
+    for _ in range(3):
+        want = batched(carry, *args)
+        got = driver._run_scan_resident(
+            carry, *args, core=core, n_steps=steps, n_shards=0
+        )
+        _assert_same(got, want)
+        carry, _ = got
+    assert int(carry.assigned[0]) > 0
+
+
+def test_scan_path_follows_instance_count(kilo_file, tmp_path):
+    path, edges, n = kilo_file
+    assert scan_path(1, 0) == "single"
+    assert scan_path(4, 0) == scan_path(4, 4) == "vmap"
+    with EdgeFileReader(path) as r:
+        res = partition_file(r, "adwise", K, chunk_edges=256, window_max=8,
+                             spill_dir=str(tmp_path))
+    assert res.stats["scan_path"] == "single"
+    z, per = 4, len(edges) // 4
+    streams = np.ascontiguousarray(edges[: z * per].reshape(z, per, 2))
+    results = partition_stream_batched(
+        streams, np.ones((z, per), bool), n, AdwiseConfig(k=K, window_max=8),
+        backend="vmap",
+    )
+    assert [r.stats["scan_path"] for r in results] == ["vmap"] * z

@@ -8,7 +8,9 @@ runs, so nothing here is a result or a time.
 The topology is described inside a module-scoped fixture, never at import:
 only the worker that runs this file loads the TPU compiler library.
 """
+import math
 import os
+import re
 import types
 
 import jax
@@ -28,6 +30,10 @@ from repro.kernels.window_score import window_score_pallas
 V = 1 << 22  # Graph500 scale 22
 K = 32
 W = 256
+# Ops that re-lay-out a whole (V+1,) vertex table; the scan's step may
+# scatter into the tables but must not run one of these over them.
+RELAYOUT_OPS = {"reduce", "broadcast", "copy", "dynamic-update-slice"}
+HLO_OP = re.compile(r"^(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* ([a-z][\w-]*)\(")
 
 
 @pytest.fixture(scope="module")
@@ -66,6 +72,49 @@ def _adwise_inputs(one_chip):
     return core, carry, vec
 
 
+def _computations(hlo: str) -> dict:
+    """``{name: instruction lines}`` of a compiled module's HLO text."""
+    comps: dict = {}
+    lines = None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            lines = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            lines = None
+        elif lines is not None and line.strip():
+            lines.append(line.strip())
+    return comps
+
+
+def step_loop_relayouts(hlo: str) -> list:
+    """The V-sized ``RELAYOUT_OPS`` that run on every step of the scan: in
+    the body of the ``while`` that carries the (V+1, K) replica table, or in
+    any computation that body calls."""
+    comps = _computations(hlo)
+    (step,) = [
+        line for lines in comps.values() for line in lines
+        if " while(" in line
+        and re.search(rf"pred\[(?:1,)?{V + 1},", line.split(" while(")[0])
+    ]
+    todo, seen = [re.search(r"body=%([^,\s]+)", step).group(1)], set()
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            todo += re.findall(r"(?:calls|body|condition|to_apply)=%([^,\s)]+)", line)
+    found = []
+    for name in seen:
+        for line in comps[name]:
+            op = HLO_OP.match(line)
+            if (op and op.group(2) in RELAYOUT_OPS
+                    and math.prod(int(d) for d in op.group(1).split(",") if d) == V + 1):
+                found.append(line)
+    return found
+
+
 def test_adwise_resident_scan_compiles_at_scale_22(one_chip):
     per = (1 << 16) + W
     core, carry, vec = _adwise_inputs(one_chip)
@@ -78,6 +127,8 @@ def test_adwise_resident_scan_compiles_at_scale_22(one_chip):
     # The (V+1, K) tables are the bulk of the arguments and fit one chip.
     assert mem.argument_size_in_bytes > (V + 1) * K
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    # A lone instance's step only scatters into its vertex tables.
+    assert step_loop_relayouts(compiled.as_text()) == []
 
 
 def test_adwise_ring_scan_compiles_at_scale_22(one_chip):
@@ -92,6 +143,7 @@ def test_adwise_ring_scan_compiles_at_scale_22(one_chip):
         vec((1,), jnp.int32), core=core, n_steps=src.scan_steps, n_shards=0,
     ).compile()
     assert compiled.memory_analysis().argument_size_in_bytes > (V + 1) * K
+    assert step_loop_relayouts(compiled.as_text()) == []
 
 
 @pytest.mark.parametrize("w,k", [(256, 32), (256, 128)])

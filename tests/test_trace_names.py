@@ -145,6 +145,7 @@ def test_ring_loop_spans_reach_the_profiler(graph_file, tmp_path):
     (meta,) = [e[3] for e in main if e[2] == "repro.partition_file"]
     assert meta["host_syncs"] == res.stats["host_syncs"]
     assert meta["scan_calls"] == calls
+    assert meta["scan_path"] == res.stats["scan_path"] == "single"
     assert meta["host_serial_s"] == pytest.approx(res.stats["host_serial_s"])
 
 
