@@ -188,7 +188,8 @@ def run_main_path(run_dir: Path, *, scale: int, edges: int, k: int,
 
     e, n, path, warm = make_graph_files(run_dir, scale, edges, chunk_edges,
                                         seed)
-    table_mb = (n + 1) * k * (1 + 4) / 1e6  # bool replicas + int32 versions
+    # Packed replica words + int32 versions (core/bitrows.py).
+    table_mb = (n + 1) * (4 * -(-k // 32) + 4) / 1e6
     log(f"state: ADWISE (V+1, K)=({n + 1}, {k}) replica/version tables "
         f"~{table_mb:.0f} MB on the device; engine replica mask (K, V) "
         f"{n * k / 1e6:.0f} MB")

@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import scoring
+from repro.core import bitrows, scoring
 from repro.core.types import AdwiseConfig, PartitionResult, WarmState
 
 __all__ = ["partition_stream", "partition_stream_batched", "WarmState"]
@@ -47,7 +47,9 @@ _BIG_I32 = np.int32(2**31 - 1)
 
 class Carry(NamedTuple):
     # Vertex cache.
-    replicas: jax.Array  # (V+1, K) bool — row V is a scatter dump.
+    # (V+1, ⌈K/32⌉) uint32, (V+1,) for K <= 32: partition p is bit p % 32
+    # of word p // 32 (core.bitrows). Row V is a scatter dump, all zero.
+    replicas: jax.Array
     rep_version: jax.Array  # (V+1,) int32
     deg: jax.Array  # (V+1,) int32
     max_deg: jax.Array  # () int32
@@ -96,20 +98,24 @@ class Carry(NamedTuple):
         (``assigned`` resets, so the Eq. 4 tolerance schedule replays); the
         window controller likewise starts fresh. Only the *graph knowledge*
         — replica table, degree table, partition loads — carries over.
+
+        The vertex tables are built on the host, the replica table packed
+        to words there: they stay host arrays until the driver stacks its
+        instances and uploads them, so a (V, K) bool never reaches the
+        device.
         """
         base = _init_carry(cfg, num_vertices, budget)
         v1 = num_vertices + 1
-        rep = jnp.zeros((v1, cfg.k), bool).at[:num_vertices].set(
-            jnp.asarray(replicas, bool)
-        )
-        deg_j = jnp.zeros((v1,), jnp.int32).at[:num_vertices].set(
-            jnp.asarray(deg, jnp.int32)
-        )
+        packed = bitrows.pack_np(replicas)
+        rep = np.zeros((v1,) + packed.shape[1:], packed.dtype)
+        rep[:num_vertices] = packed
+        deg_np = np.zeros((v1,), np.int32)
+        deg_np[:num_vertices] = deg
         return base._replace(
             replicas=rep,
-            deg=deg_j,
-            max_deg=jnp.maximum(jnp.max(deg_j), 1),
-            sizes=jnp.asarray(sizes, jnp.int32),
+            deg=deg_np,
+            max_deg=np.int32(max(int(deg_np.max()), 1)),
+            sizes=np.asarray(sizes, np.int32),
         )
 
 
@@ -126,7 +132,7 @@ def _init_carry(cfg: AdwiseConfig, num_vertices: int, budget: float) -> Carry:
     zi = jnp.zeros((), jnp.int32)
     zf = jnp.zeros((), jnp.float32)
     return Carry(
-        replicas=jnp.zeros((v1, k), bool),
+        replicas=bitrows.zeros(v1, k),
         rep_version=jnp.zeros((v1,), jnp.int32),
         deg=jnp.zeros((v1,), jnp.int32),
         max_deg=jnp.ones((), jnp.int32),
@@ -243,8 +249,8 @@ def _make_step(
             sel_c = jnp.clip(sel_idx, 0, w_max - 1)
 
             # ---- 3) Fresh R (+ CS) for the selected rows. ----
-            rep_u = carry.replicas[u]  # (W, K)
-            rep_v = carry.replicas[v]
+            rep_u = bitrows.unpack(carry.replicas[u], k)  # (W, K) bool
+            rep_v = bitrows.unpack(carry.replicas[v], k)
             r_all = scoring.replication_score(rep_u, rep_v, deg[u], deg[v], max_deg)
             rcs_rows = r_all[sel_c]
             if cfg.use_clustering:
@@ -317,14 +323,25 @@ def _make_step(
             # ---- 6) Apply assignments to the vertex cache / partition state. ----
             chi = ch.astype(jnp.int32)
             sizes = sizes_net.at[ch_p].add(chi)  # adds 0 where not chosen
-            u_c = jnp.where(ch, u, v_dummy)
-            v_c = jnp.where(ch, v, v_dummy)
-            old_u = carry.replicas[u_c, ch_p]
-            old_v = carry.replicas[v_c, ch_p]
-            replicas = carry.replicas.at[u_c, ch_p].max(ch).at[v_c, ch_p].max(ch)
-            new_u = (ch & ~old_u).astype(jnp.int32)
-            new_v = (ch & ~old_v).astype(jnp.int32)
-            rep_version = carry.rep_version.at[u_c].add(new_u).at[v_c].add(new_v)
+            # The vertex tables take the (at most b) chosen slots only: a
+            # scatter into a V-sized table costs per entry, not per table.
+            (slots,) = jnp.nonzero(ch, size=b, fill_value=w_max)
+            on = slots < w_max
+            s_c = jnp.minimum(slots, w_max - 1)
+            p_c = ch_p[s_c]
+            u_c = jnp.where(on, u[s_c], v_dummy)
+            v_c = jnp.where(on, v[s_c], v_dummy)
+            # Chosen slots share no vertex, so two entries meet on one word
+            # only as a self-loop (one bit) or in the dump row (no bit).
+            uv_c = jnp.concatenate([u_c, v_c])
+            on2 = jnp.concatenate([on, on])
+            old, replicas = bitrows.set_bit(
+                carry.replicas, uv_c, jnp.concatenate([p_c, p_c]), on2
+            )
+            # A self-loop's vertex gains two versions, as u and v each did.
+            rep_version = carry.rep_version.at[uv_c].add(
+                (on2 & ~old).astype(jnp.int32)
+            )
             win_valid = win_valid & ~ch
             n_valid = n_valid - n_ch
             assigned = carry.assigned + n_ch

@@ -1141,6 +1141,14 @@ class HostSerial:
     finish = dispatch
 
 
+def _stack_instances(*xs: Any) -> jax.Array:
+    """One carry leaf of z instances on a leading axis: host leaves (a
+    warm carry's vertex tables) stack on the host and upload once."""
+    if all(isinstance(x, (np.ndarray, np.generic)) for x in xs):
+        return jnp.asarray(np.stack(xs))
+    return jnp.stack(xs)
+
+
 class ScanDriver:
     """One streaming-scan engine for every step-core strategy.
 
@@ -1228,7 +1236,7 @@ class ScanDriver:
                 "prev_read to the FileSource instead"
             )
             carries = [core.warm_carry(budget, w) for w in warm]
-            carry = jax.tree.map(lambda *xs: jnp.stack(xs), *carries)
+            carry = jax.tree.map(_stack_instances, *carries)
             if prev_np is not None and all(has_prev):
                 for i, w in enumerate(warm):
                     assert w.prev_assign is not None  # all(has_prev) above
@@ -1243,6 +1251,12 @@ class ScanDriver:
             ids = np.asarray(instance_ids)
             assert ids.shape == (z,), (ids.shape, z)
         carry = core.seed_instances(carry, z, ids)
+        # One instance's V-sized tables, as the carry holds them.
+        v1 = getattr(core, "num_vertices", num_vertices) + 1
+        self.vertex_table_bytes = sum(
+            x.nbytes for x in jax.tree.leaves(carry)
+            if x.ndim >= 2 and x.shape[1] == v1
+        ) // z
         self.fixed_cost = cost_per_score is not None
         if cost_per_score is not None:
             carry = core.set_cost(carry, cost_per_score, z)
@@ -1578,4 +1592,5 @@ class ScanDriver:
             spans_prestaged=res.spans_prestaged,
             spans_missed=res.spans_missed,
             prestage_wall_s=res.prestage_wall_s,
+            vertex_table_bytes=self.vertex_table_bytes,
         )

@@ -608,6 +608,7 @@ def _run_restream_chunks(
         prestage_wall_s=prestage_wall_s,
         scan_calls=scan_calls,
         scan_path=pass_stats[0].get("scan_path"),
+        vertex_table_bytes=pass_stats[0].get("vertex_table_bytes"),
         buffer_rows=buffer_rows,
         wall_time_s=time.perf_counter() - t0,
     )
@@ -867,6 +868,8 @@ def partition_file(
                     scan_calls=stats.get("scan_calls", 0))
     if stats.get("scan_path"):
         counters["scan_path"] = stats["scan_path"]
+    if stats.get("vertex_table_bytes"):
+        counters["vertex_table_bytes"] = stats["vertex_table_bytes"]
     whole.set(**counters)
     whole.close()
     if tr.enabled:

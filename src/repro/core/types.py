@@ -43,9 +43,13 @@ class WarmState(NamedTuple):
     re-places the stream. 2PS(-L) reuse ``replicas`` as the cluster→partition
     table: phase 1 leaves each clustered vertex with exactly one virtual
     replica on its cluster's partition.
+
+    The tables are host arrays. ``replicas`` is a bool table on the host
+    only: ADWISE packs it into its carry's uint32 words there
+    (:mod:`repro.core.bitrows`) and uploads the words.
     """
 
-    replicas: np.ndarray  # (V, K) bool
+    replicas: np.ndarray  # (V, K) bool, on the host
     deg: np.ndarray  # (V,) int — full (or partial) streamed degrees
     sizes: np.ndarray  # (K,) int — partition loads at warm-start time
     prev_assign: Optional[np.ndarray] = None  # (m,) int32, -1 = none

@@ -143,8 +143,13 @@ def quality_from_chunks(
     ``unassigned``, plus the accumulated ``replicas`` table itself (callers
     that need both the numbers and the table — e.g. re-streaming warm starts
     — get them from the single read). Matches the in-memory metrics exactly.
+
+    The numbers are read from the rows an assigned edge touched only: every
+    other row is all False and adds nothing to either count, and a pass over
+    the whole (V, k) table costs seconds when V·k reaches billions.
     """
     rep = np.zeros((num_vertices, k), dtype=bool)
+    touched = np.zeros(num_vertices, dtype=bool)
     sizes = np.zeros(k, dtype=np.int64)
     n_unassigned = 0
     for edges, assign in pairs:
@@ -154,15 +159,18 @@ def quality_from_chunks(
         n_unassigned += int((~ok).sum())
         rep[edges[ok, 0], assign[ok]] = True
         rep[edges[ok, 1], assign[ok]] = True
+        touched[edges[ok, 0]] = True
+        touched[edges[ok, 1]] = True
         sizes += np.bincount(assign[ok], minlength=k).astype(np.int64)
     mx = sizes.max() if k else 0
     imbalance = float((mx - sizes.min()) / mx) if mx > 0 else 0.0
+    rows = rep[np.flatnonzero(touched)]
     return dict(
-        replication_degree=replication_degree(rep),
+        replication_degree=replication_degree(rows),
         imbalance=imbalance,
         sizes=sizes,
         unassigned=n_unassigned,
-        sync_volume=sync_volume(rep),
+        sync_volume=sync_volume(rows),
         replicas=rep,
     )
 

@@ -15,6 +15,7 @@ from repro.graph import (
     replica_sets_from_chunks,
     replication_degree,
     rmat,
+    sync_volume,
 )
 from repro.graph.io import (
     HEADER_BYTES,
@@ -352,6 +353,28 @@ def test_chunked_metrics_match_in_memory(graph_file):
     assert q["replication_degree"] == replication_degree(ref_rep)
     assert q["imbalance"] == partition_balance(assign, k)
     assert q["unassigned"] == 0
+
+
+@pytest.mark.parametrize("unassigned", ["raise", "drop"])
+def test_chunked_metrics_on_sparse_vertex_table(unassigned):
+    """Most of the (V, k) table untouched (isolated vertices, a prefix of a
+    stream), self-loops and repeated edges: the numbers read from the touched
+    rows equal those of the whole table."""
+    rng = np.random.default_rng(7)
+    n, k, m = 5000, 37, 400
+    edges = rng.integers(0, 300, (m, 2)).astype(np.int32) * 13
+    edges[::9, 1] = edges[::9, 0]
+    edges[1::11] = edges[0]
+    assign = rng.integers(0, k, m).astype(np.int32)
+    if unassigned == "drop":
+        assign[::7] = -1
+    pairs = ((edges[s : s + 64], assign[s : s + 64]) for s in range(0, m, 64))
+    q = quality_from_chunks(pairs, n, k, unassigned=unassigned)
+    ref_rep = replica_sets_from_assignment(edges, assign, n, k,
+                                           unassigned=unassigned)
+    assert (q["replicas"] == ref_rep).all()
+    assert q["replication_degree"] == replication_degree(ref_rep)
+    assert q["sync_volume"] == sync_volume(ref_rep) > 0
 
 
 def test_chunked_metrics_unassigned_policies(graph_file):

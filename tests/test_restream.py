@@ -4,6 +4,7 @@ Streams in the property tests are adversarial by construction: self-loops, dupli
 edges, star graphs (which stall the vertex-disjoint top-b pick), empty
 streams and streams shorter than the assign batch.
 """
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -16,6 +17,7 @@ from repro.core import (
     run_partitioner,
     warm_from_assignment,
 )
+from repro.core import bitrows
 from repro.core.adwise import Carry
 from repro.core.restream import streaming_vertex_clustering
 from repro.graph import (
@@ -125,8 +127,10 @@ def test_warm_start_carry_fields():
     deg = np.arange(v)
     sizes = np.array([5, 1, 2])
     carry = Carry.warm_start(cfg, v, 0.0, replicas=replicas, deg=deg, sizes=sizes)
-    assert carry.replicas.shape == (v + 1, 3)  # scatter-dump row appended
-    assert bool(carry.replicas[1, 2]) and not bool(carry.replicas[v].any())
+    # One packed word per row (k <= 32), the scatter-dump row appended.
+    assert carry.replicas.shape == (v + 1,) and carry.replicas.dtype == np.uint32
+    rows = np.asarray(bitrows.unpack(jnp.asarray(carry.replicas), 3))
+    assert rows[1, 2] and rows.sum() == 1 and not rows[v].any()
     assert carry.deg[:v].tolist() == deg.tolist()
     assert int(carry.max_deg) == v - 1
     assert carry.sizes.tolist() == sizes.tolist()

@@ -20,6 +20,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro import compat
+from repro.core import bitrows
 from repro.core.driver import AdwiseCore, FileSource, RingBuf, _run_scan_resident, _run_scan_ring
 from repro.core.types import AdwiseConfig
 from repro.engine.algorithms import pagerank_update
@@ -61,8 +62,8 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _adwise_inputs(one_chip):
-    core = AdwiseCore(cfg=AdwiseConfig(k=K, window_max=W), num_vertices=V)
+def _adwise_inputs(one_chip, v=V, k=K):
+    core = AdwiseCore(cfg=AdwiseConfig(k=k, window_max=W), num_vertices=v)
     base = jax.eval_shape(lambda: core.init_carry(0.0))
     carry = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct((1,) + s.shape, s.dtype, sharding=one_chip),
@@ -89,13 +90,14 @@ def _computations(hlo: str) -> dict:
 
 def step_loop_relayouts(hlo: str) -> list:
     """The V-sized ``RELAYOUT_OPS`` that run on every step of the scan: in
-    the body of the ``while`` that carries the (V+1, K) replica table, or in
-    any computation that body calls."""
+    the body of the ``while`` that carries the replica words and the
+    (W, 2) window, or in any computation that body calls."""
     comps = _computations(hlo)
     (step,) = [
         line for lines in comps.values() for line in lines
         if " while(" in line
-        and re.search(rf"pred\[(?:1,)?{V + 1},", line.split(" while(")[0])
+        and re.search(rf"u32\[(?:1,)?{V + 1}[],]", line.split(" while(")[0])
+        and re.search(rf"s32\[(?:1,)?{W},2\]", line.split(" while(")[0])
     ]
     todo, seen = [re.search(r"body=%([^,\s]+)", step).group(1)], set()
     while todo:
@@ -124,26 +126,43 @@ def test_adwise_resident_scan_compiles_at_scale_22(one_chip):
         core=core, n_steps=1024, n_shards=0,
     ).compile()
     mem = compiled.memory_analysis()
-    # The (V+1, K) tables are the bulk of the arguments and fit one chip.
-    assert mem.argument_size_in_bytes > (V + 1) * K
+    # The packed (V+1, ⌈K/32⌉) replica words are the bulk of the arguments
+    # and fit one chip.
+    assert mem.argument_size_in_bytes > (V + 1) * bitrows.words(K) * 4
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
     # A lone instance's step only scatters into its vertex tables.
     assert step_loop_relayouts(compiled.as_text()) == []
 
 
-def test_adwise_ring_scan_compiles_at_scale_22(one_chip):
-    """The file path's program: the donated ring rides in the carry."""
-    cfg = AdwiseConfig(k=K, window_max=W)
+def _compile_ring_scan(one_chip, v, k, chunk_edges):
+    cfg = AdwiseConfig(k=k, window_max=W)
     src = FileSource([types.SimpleNamespace(num_edges=1 << 20)],
-                     chunk_edges=1 << 16, cfg=cfg)
-    core, carry, vec = _adwise_inputs(one_chip)
+                     chunk_edges=chunk_edges, cfg=cfg)
+    core, carry, vec = _adwise_inputs(one_chip, v, k)
     buf = RingBuf(uv=vec((1, src.B, 2), jnp.int32), prev=vec((1, src.B), jnp.int32))
-    compiled = _run_scan_ring.lower(
-        (carry, buf), vec((1,), jnp.int32), vec((1, K), jnp.bool_),
+    return _run_scan_ring.lower(
+        (carry, buf), vec((1,), jnp.int32), vec((1, k), jnp.bool_),
         vec((1,), jnp.int32), core=core, n_steps=src.scan_steps, n_shards=0,
     ).compile()
-    assert compiled.memory_analysis().argument_size_in_bytes > (V + 1) * K
+
+
+def test_adwise_ring_scan_compiles_at_scale_22(one_chip):
+    """The file path's program: the donated ring rides in the carry."""
+    compiled = _compile_ring_scan(one_chip, V, K, 1 << 16)
+    assert (compiled.memory_analysis().argument_size_in_bytes
+            > (V + 1) * bitrows.words(K) * 4)
     assert step_loop_relayouts(compiled.as_text()) == []
+
+
+def test_adwise_ring_scan_compiles_at_scale_25_k512(one_chip):
+    """Graph500 SCALE 25 at k = 512 on one chip: as (V+1, K) bools the
+    replica table alone is 17.2 GB; packed it is 2.15 GB."""
+    v, k = 1 << 25, 512
+    mem = _compile_ring_scan(one_chip, v, k, 8192).memory_analysis()
+    # Replica words, versions and degrees, all donated with the carry.
+    assert mem.argument_size_in_bytes >= (v + 1) * (bitrows.words(k) + 2) * 4
+    assert mem.argument_size_in_bytes < 3e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
 @pytest.mark.parametrize("w,k", [(256, 32), (256, 128)])
